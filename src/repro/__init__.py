@@ -5,14 +5,4 @@ Package layout: core/ (the paper's algorithm), models/, optim/, data/,
 checkpoint/, configs/, kernels/ (Pallas), launch/ (mesh, train, serve, dryrun).
 """
 
-import jax as _jax
-
-# Pin the classic GSPMD partitioner. Shardy (the JAX 0.8 default) lowers
-# with_sharding_constraint inside shard_map *manual-axes* bodies as fully-open
-# ``sdy.sharding_constraint [{?}...]`` hints, dropping the named-axis
-# assignment — measured +54 GiB/device of replicated vocab/payload tensors on
-# the 16x16 production mesh (see DESIGN.md §Known-limitations). Revisit when
-# Shardy honours closed constraints under manual subgroups.
-_jax.config.update("jax_use_shardy_partitioner", False)
-
 __version__ = "0.1.0"

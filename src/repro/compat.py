@@ -1,98 +1,33 @@
-"""Version shims over jax APIs that moved between releases.
+"""The repo's one spelling of ``shard_map``.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to ``jax.shard_map``
-(and grew ``axis_names=`` / ``check_vma=`` in place of ``auto=`` /
-``check_rep=``).  Call sites in this repo always use the NEW keyword style:
+Call sites use the keyword style of ``jax.shard_map``:
 
     shard_map(f, mesh=mesh, in_specs=..., out_specs=...,
               axis_names={...}, check_vma=False)
 
-and this module translates to whatever the installed jax provides:
-
-* new jax:  forwarded verbatim (``axis_names`` dropped if the installed
-  ``jax.shard_map`` predates it and the call manualizes every mesh axis).
-* old jax (<= 0.4.x): routed to ``jax.experimental.shard_map.shard_map`` with
-  ``auto = mesh.axis_names - axis_names`` and ``check_rep = check_vma``.
+with ``axis_names=None`` meaning "manualize every mesh axis".
 """
 
 from __future__ import annotations
 
-import inspect
-from typing import Any
-
 import jax
 
-__all__ = ["shard_map", "axis_size", "supports_nested_manual"]
+__all__ = ["shard_map", "supports_nested_manual"]
 
 
 def supports_nested_manual() -> bool:
     """Whether this jax/XLA can nest a shard_map that completes the
-    manualization inside an already partial-manual body.
-
-    On 0.4.x the SPMD partitioner RET_CHECKs (``IsManualSubgroup``) on the
-    nested pattern; callers fall back to keeping the inner axes auto (GSPMD
-    constraints) instead of the nested fully-manual map (DESIGN.md §6).
-    """
-    return _NEW is not None
-
-
-def axis_size(name) -> "jax.Array | int":
-    """``jax.lax.axis_size`` (added after 0.4) with a ``psum(1, name)``
-    fallback — inside a shard_map/pmap body both yield the mapped size."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(name)
-    return jax.lax.psum(1, name)
-
-_NEW = getattr(jax, "shard_map", None)
-
-if _NEW is None:
-    try:  # pragma: no cover - exercised only on old jax
-        from jax.experimental.shard_map import shard_map as _LEGACY
-    except ImportError:  # pragma: no cover
-        _LEGACY = None
-else:
-    _LEGACY = None
+    manualization inside an already partial-manual body (DESIGN.md §6).
+    True on the installed jax; the callers' fallbacks are kept until they are
+    removed with their tests."""
+    return True
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check_vma=False):
-    """New-style ``shard_map`` on any supported jax version.
-
-    ``axis_names`` — the mesh axes the body manualizes (``None`` = all of
-    them); ``check_vma`` — replication/varying-manual-axes checking (named
-    ``check_rep`` before jax 0.5).
-    """
-    if _NEW is not None:
-        kwargs: dict[str, Any] = dict(
-            mesh=mesh, in_specs=in_specs, out_specs=out_specs
-        )
-        params = inspect.signature(_NEW).parameters
-        if axis_names is not None:
-            if "axis_names" in params:
-                kwargs["axis_names"] = set(axis_names)
-            elif "auto" in params:
-                # transitional signature: manual axes are implied, the
-                # complement is passed as auto
-                kwargs["auto"] = frozenset(
-                    a for a in mesh.axis_names if a not in set(axis_names)
-                )
-            elif set(axis_names) != set(mesh.axis_names):
-                raise NotImplementedError(
-                    "installed jax.shard_map supports neither axis_names= nor "
-                    "auto=; partial-manual mapping is not expressible"
-                )
-        if "check_vma" in params:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in params:
-            kwargs["check_rep"] = check_vma
-        return _NEW(f, **kwargs)
-
-    if _LEGACY is None:  # pragma: no cover
-        raise ImportError("no shard_map implementation found in this jax")
-
-    manual = set(mesh.axis_names) if axis_names is None else set(axis_names)
-    auto = frozenset(a for a in mesh.axis_names if a not in manual)
-    return _LEGACY(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma, auto=auto,
-    )
+    """``jax.shard_map``; ``axis_names`` — the mesh axes the body manualizes
+    (``None`` = all of them)."""
+    kwargs = {}
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma, **kwargs)

@@ -30,7 +30,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..quantization import uniform_from_bits
+from ..quantization import pow2, uniform_from_bits
 from .base import Compressor, Payload
 
 __all__ = ["NaturalCompressor"]
@@ -94,7 +94,7 @@ class NaturalCompressor(Compressor):
 
     def decode(self, payload: Payload, d: int) -> jax.Array:
         code = payload.packed
-        mag = jnp.exp2((jnp.abs(code) - _BIAS).astype(jnp.float32))
+        mag = pow2(jnp.abs(code).astype(jnp.int32) - _BIAS)
         return jnp.where(
             code == 0, 0.0, jnp.sign(code).astype(jnp.float32) * mag
         )[:d]

@@ -38,6 +38,7 @@ __all__ = [
     "num_blocks",
     "quantize_blocks_from_uniform",
     "uniform_from_bits",
+    "pow2",
 ]
 
 
@@ -51,9 +52,29 @@ def uniform_from_bits(bits: jax.Array) -> jax.Array:
     kernel routes bitwise-EQUAL to the pure-jnp fallbacks given the same
     bits, not merely equal in distribution.
     """
-    return (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(
-        1.0 / (1 << 24)
-    )
+    # The top 24 bits fit an int32 exactly; the signed convert is the one
+    # Mosaic lowers (it refuses uint32 -> f32), and the value is the same.
+    top = jax.lax.bitcast_convert_type(bits >> jnp.uint32(8), jnp.int32)
+    return top.astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+
+
+def pow2(e: jax.Array) -> jax.Array:
+    """Exact f32 ``2.0 ** e`` for integer ``e``, written into the exponent
+    bits: subnormal below ``2^-126``, 0 below ``2^-149``, inf above
+    ``2^127``.
+
+    THE one power-of-two map of natural compression's decode, shared by the
+    fallback and the kernels like :func:`uniform_from_bits`: ``jnp.exp2`` is
+    a transcendental approximation whose XLA and Mosaic lowerings disagree
+    on a TPU, while this is exact on every backend.
+    """
+    e = e.astype(jnp.int32)
+    normal = jax.lax.bitcast_convert_type(
+        (jnp.clip(e, -126, 127) + 127) << 23, jnp.float32)
+    sub = jax.lax.bitcast_convert_type(
+        jnp.left_shift(jnp.int32(1), jnp.clip(e + 149, 0, 22)), jnp.float32)
+    out = jnp.where(e >= -126, normal, jnp.where(e >= -149, sub, 0.0))
+    return jnp.where(e > 127, jnp.inf, out)
 
 
 # ---------------------------------------------------------------------------

@@ -18,10 +18,11 @@ Bitwise agreement with the frexp oracle (:func:`repro.kernels.ref.ref_nat_pack`)
 holds on ALL finite inputs including subnormals — that equality is a real test
 of the bit trick and is enforced in CI under ``interpret=True``.
 
-Decode_sum: the server unpacks each worker's codes (``sign * 2^(|code|-BIAS)``
-via ``exp2`` on the VPU) and accumulates in place over the sequential TPU
-grid, so no ``(n, d)`` dense float tensor ever materialises in HBM — traffic
-is ``2nd`` bytes of codes in, ``4d`` bytes out.  The ``_apply`` variant fuses
+Decode_sum: the server unpacks each worker's codes (``sign * 2^(|code|-BIAS)``,
+the power of two written into the exponent bits by ``pow2``) and accumulates in place over the ``(m_tiles, n)``
+grid, worker axis innermost, so no ``(n, d)`` dense float tensor ever
+materialises in HBM — traffic is ``2nd`` bytes of codes in, ``4d`` bytes
+out.  The ``_apply`` variant fuses
 DIANA's server memory update into the last grid step (see
 :mod:`repro.kernels.unpack_reduce` for the pattern).
 
@@ -40,7 +41,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.quantization import pad_axis_to_multiple
+from repro.core.quantization import (
+    pad_axis_to_multiple, pow2, uniform_from_bits,
+)
+
+from .unpack_reduce import DECODE_PARAMS
 
 __all__ = [
     "nat_pack",
@@ -63,24 +68,20 @@ DEFAULT_TILE_M = 8
 # ---------------------------------------------------------------------------
 
 def _encode_body(x, bits):
-    """f32 tile + uint32 bits -> int16 nat codes, bitwise == the frexp oracle."""
-    u = (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(
-        1.0 / (1 << 24)
-    )
-    b0 = jax.lax.bitcast_convert_type(jnp.abs(x), jnp.uint32)
+    """f32 tile + uint32 bits -> int16 nat codes, bitwise == the frexp oracle.
+
+    The float's bit pattern is read as int32 (|x| has a zero sign bit, so
+    every field is non-negative): Mosaic converts int32, not uint32, to f32.
+    """
+    u = uniform_from_bits(bits)
+    b0 = jax.lax.bitcast_convert_type(jnp.abs(x), jnp.int32)
     # Subnormals have a zero exponent field; scaling by 2^24 is exact and
     # moves them into the normal range so one code path covers everything.
-    is_sub = ((b0 >> jnp.uint32(23)) == 0) & (x != 0.0)
+    is_sub = ((b0 >> 23) == 0) & (x != 0.0)
     xs = jnp.where(is_sub, x * jnp.float32(1 << 24), x)
-    bs = jax.lax.bitcast_convert_type(jnp.abs(xs), jnp.uint32)
-    p_up = (bs & jnp.uint32(0x7FFFFF)).astype(jnp.float32) * jnp.float32(
-        2.0 ** -23
-    )
-    expo = (
-        (bs >> jnp.uint32(23)).astype(jnp.int32)
-        - 127
-        - jnp.where(is_sub, 24, 0)
-    )
+    bs = jax.lax.bitcast_convert_type(jnp.abs(xs), jnp.int32)
+    p_up = (bs & 0x7FFFFF).astype(jnp.float32) * jnp.float32(2.0 ** -23)
+    expo = (bs >> 23) - 127 - jnp.where(is_sub, 24, 0)
     chosen = expo + (u < p_up).astype(jnp.int32)
     sign = jnp.where(x < 0.0, -1, 1)
     code = sign * (chosen + NAT_BIAS)
@@ -92,7 +93,7 @@ def _kernel(x_ref, bits_ref, out_ref):
 
 
 def _kernel_prng(seed_ref, x_ref, out_ref):
-    pltpu.prng_seed(seed_ref[0], seed_ref[1], pl.program_id(0))
+    pltpu.prng_seed(seed_ref[0] + pl.program_id(0), seed_ref[1])
     bits = pltpu.bitcast(pltpu.prng_random_bits(x_ref.shape), jnp.uint32)
     out_ref[...] = _encode_body(x_ref[...], bits)
 
@@ -165,7 +166,7 @@ def nat_pack_prng(
 
 def _decode_body(codes):
     c = codes.astype(jnp.int32)
-    mag = jnp.exp2((jnp.abs(c) - NAT_BIAS).astype(jnp.float32))
+    mag = pow2(jnp.abs(c) - NAT_BIAS)
     sign = jnp.sign(c).astype(jnp.float32)
     return jnp.where(c == 0, 0.0, sign * mag)
 
@@ -185,21 +186,21 @@ def _accumulate(i, dense, out_ref):
 
 
 def _sum_kernel(codes_ref, out_ref):
-    _accumulate(pl.program_id(0), _decode_body(codes_ref[0]), out_ref)
+    _accumulate(pl.program_id(1), _decode_body(codes_ref[0]), out_ref)
 
 
 def _mean_kernel(codes_ref, out_ref, *, n):
     _sum_kernel(codes_ref, out_ref)
 
-    @pl.when(pl.program_id(0) == n - 1)
+    @pl.when(pl.program_id(1) == n - 1)
     def _mean():
         out_ref[...] = out_ref[...] / jnp.float32(n)
 
 
 def _apply_kernel(codes_ref, h_ref, ghat_ref, newh_ref, *, n, alpha):
-    _accumulate(pl.program_id(0), _decode_body(codes_ref[0]), ghat_ref)
+    _accumulate(pl.program_id(1), _decode_body(codes_ref[0]), ghat_ref)
 
-    @pl.when(pl.program_id(0) == n - 1)
+    @pl.when(pl.program_id(1) == n - 1)
     def _apply():
         dm = ghat_ref[...] / jnp.float32(n)
         h = h_ref[...]
@@ -212,6 +213,11 @@ def _codes_rows(codes: jax.Array, tile_m: int) -> jax.Array:
     n, d = codes.shape
     c = pad_axis_to_multiple(codes, LANES * tile_m, axis=1)
     return c.reshape(n, -1, LANES)
+
+
+def _codes_spec(tile_m: int):
+    """Worker i's codes for output tile j, on the ``(m_tiles, n)`` grid."""
+    return pl.BlockSpec((1, tile_m, LANES), lambda j, i: (i, j, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("tile_m", "interpret"))
@@ -227,10 +233,11 @@ def nat_decode_sum(
     n, mp, _ = c.shape
     out = pl.pallas_call(
         _sum_kernel,
-        grid=(n, mp // tile_m),
-        in_specs=[pl.BlockSpec((1, tile_m, LANES), lambda i, j: (i, j, 0))],
-        out_specs=pl.BlockSpec((tile_m, LANES), lambda i, j: (j, 0)),
+        grid=(mp // tile_m, n),
+        in_specs=[_codes_spec(tile_m)],
+        out_specs=pl.BlockSpec((tile_m, LANES), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, LANES), jnp.float32),
+        compiler_params=DECODE_PARAMS,
         interpret=interpret,
     )(c)
     return out.reshape(-1)[:d]
@@ -249,10 +256,11 @@ def nat_decode_sum_mean(
     n, mp, _ = c.shape
     out = pl.pallas_call(
         functools.partial(_mean_kernel, n=n),
-        grid=(n, mp // tile_m),
-        in_specs=[pl.BlockSpec((1, tile_m, LANES), lambda i, j: (i, j, 0))],
-        out_specs=pl.BlockSpec((tile_m, LANES), lambda i, j: (j, 0)),
+        grid=(mp // tile_m, n),
+        in_specs=[_codes_spec(tile_m)],
+        out_specs=pl.BlockSpec((tile_m, LANES), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, LANES), jnp.float32),
+        compiler_params=DECODE_PARAMS,
         interpret=interpret,
     )(c)
     return out.reshape(-1)[:d]
@@ -282,19 +290,20 @@ def nat_decode_sum_apply(
     )
     ghat, newh = pl.pallas_call(
         functools.partial(_apply_kernel, n=n, alpha=float(alpha)),
-        grid=(n, mp // tile_m),
+        grid=(mp // tile_m, n),
         in_specs=[
-            pl.BlockSpec((1, tile_m, LANES), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((tile_m, LANES), lambda i, j: (j, 0)),
+            _codes_spec(tile_m),
+            pl.BlockSpec((tile_m, LANES), lambda j, i: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tile_m, LANES), lambda i, j: (j, 0)),
-            pl.BlockSpec((tile_m, LANES), lambda i, j: (j, 0)),
+            pl.BlockSpec((tile_m, LANES), lambda j, i: (j, 0)),
+            pl.BlockSpec((tile_m, LANES), lambda j, i: (j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((mp, LANES), jnp.float32),
             jax.ShapeDtypeStruct((mp, LANES), jnp.float32),
         ],
+        compiler_params=DECODE_PARAMS,
         interpret=interpret,
     )(c, h2)
     return ghat.reshape(-1)[:d], newh.reshape(-1)[:d]

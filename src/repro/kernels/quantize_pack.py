@@ -10,8 +10,9 @@ paper's CPU-side quantize + Elias-encode step (DESIGN.md §2).
 Tiling: the grid walks ``m`` (number of blocks) in tiles of ``TILE_M`` rows of
 ``B = block_size`` lanes.  ``B`` is a multiple of 128 in every production
 config, so rows map cleanly onto VPU lanes; the packed output has ``B/4``
-bytes per row (int8 lanes).  VMEM footprint per grid step is
-``TILE_M * B * (4 + 4 + 1 + 0.25)`` bytes — with the default TILE_M=8 and
+bytes per row, byte ``j`` holding the codes of lanes ``j, j+B/4, j+B/2,
+j+3B/4`` (four lane-slices; see ``repro.core.packing``).  VMEM footprint per
+grid step is ``TILE_M * B * (4 + 4 + 1 + 0.25)`` bytes — with the default TILE_M=8 and
 B=2048 that is ~150 KiB, far under the ~16 MiB VMEM budget, leaving headroom
 for double buffering.
 
@@ -22,11 +23,12 @@ Randomness — two variants sharing one quantization body:
   against :func:`repro.kernels.ref.ref_quantize_pack`.
 * :func:`quantize_pack_prng` (compiled TPU only) draws the bits INSIDE the
   kernel with ``pltpu.prng_seed`` + ``pltpu.prng_random_bits``, seeded per
-  tile from two key words + the grid index.  This removes the uint32 bits
-  operand entirely — 4 bytes/dim of pure HBM input traffic, as large as the
-  gradient itself — cutting the encode's HBM reads roughly in half.  Values
-  agree with the bits variant in distribution, not bitwise (independent
-  stream), which is already the stated contract for the kernel encode.
+  tile from the key's two words (the first offset by the grid index).  This
+  removes the uint32 bits operand entirely — 4 bytes/dim of pure HBM input
+  traffic, as large as the gradient itself — cutting the encode's HBM reads
+  roughly in half.  Values agree with the bits variant in distribution, not
+  bitwise (independent stream), which is already the stated contract for the
+  kernel encode.
 """
 
 from __future__ import annotations
@@ -39,7 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.quantization import pad_axis_to_multiple
+from repro.core.packing import pack2bit
+from repro.core.quantization import pad_axis_to_multiple, uniform_from_bits
 
 __all__ = ["quantize_pack", "quantize_pack_prng", "DEFAULT_TILE_M"]
 
@@ -60,25 +63,12 @@ def _quantize_body(delta, bits, packed_ref, scales_ref, *, p: float):
 
     safe = jnp.where(scale > 0, scale, 1.0)
     probs = jnp.abs(delta) / safe
-    u = (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(
-        1.0 / (1 << 24)
-    )
-    xi = (u < probs).astype(jnp.int8)
-    signs = jnp.sign(delta).astype(jnp.int8) * xi       # {-1, 0, 1}
-
-    # 2-bit pack: code = sign + 1 in {0,1,2}; 4 codes / byte, little-endian.
-    # (shifts unrolled — Pallas kernels may not capture constant arrays)
-    codes = (signs + 1).astype(jnp.uint8)
-    tm, b = codes.shape
-    g = codes.reshape(tm, b // 4, 4)
-    packed = (
-        g[..., 0]
-        | (g[..., 1] << jnp.uint8(2))
-        | (g[..., 2] << jnp.uint8(4))
-        | (g[..., 3] << jnp.uint8(6))
-    )
-    packed_ref[...] = packed.astype(jnp.uint8)
+    xi = uniform_from_bits(bits) < probs
+    # Signs stay int32 (the VPU has no 8-bit arithmetic) until pack2bit
+    # narrows the packed bytes.
+    signs = jnp.where(xi, jnp.sign(delta).astype(jnp.int32), 0)
     scales_ref[...] = scale.astype(jnp.float32)
+    packed_ref[...] = pack2bit(signs)
 
 
 def _kernel(delta_ref, bits_ref, packed_ref, scales_ref, *, p: float):
@@ -86,9 +76,10 @@ def _kernel(delta_ref, bits_ref, packed_ref, scales_ref, *, p: float):
 
 
 def _kernel_prng(seed_ref, delta_ref, packed_ref, scales_ref, *, p: float):
-    # Per-tile stream: two key words + the grid index, so every tile of
-    # blocks draws independent bits regardless of launch shape.
-    pltpu.prng_seed(seed_ref[0], seed_ref[1], pl.program_id(0))
+    # Per-tile stream: the key's first word offset by the grid index (the
+    # chip's seed takes two words), so every tile of blocks draws its own
+    # bits regardless of launch shape.
+    pltpu.prng_seed(seed_ref[0] + pl.program_id(0), seed_ref[1])
     bits = pltpu.bitcast(
         pltpu.prng_random_bits(delta_ref.shape), jnp.uint32
     )

@@ -23,7 +23,7 @@ from repro.core.packing import pack2bit, unpack2bit
 # The one bits->uniform map, shared with every fallback operator (re-exported
 # here for the kernel tests; the definition lives with the quantizers so the
 # operators never import the kernel package).
-from repro.core.quantization import lp_norm, uniform_from_bits
+from repro.core.quantization import lp_norm, pow2, uniform_from_bits
 
 __all__ = [
     "uniform_from_bits",
@@ -109,7 +109,7 @@ def ref_nat_pack(x: jax.Array, bits: jax.Array) -> jax.Array:
 
 
 def _nat_decode(code: jax.Array) -> jax.Array:
-    mag = jnp.exp2((jnp.abs(code) - NAT_BIAS).astype(jnp.float32))
+    mag = pow2(jnp.abs(code).astype(jnp.int32) - NAT_BIAS)
     return jnp.where(code == 0, 0.0, jnp.sign(code).astype(jnp.float32) * mag)
 
 

@@ -1,14 +1,16 @@
 """Server-side decode: unpack 2-bit ternary payloads and accumulate the sum
 over workers — the Pallas realisation of DIANA's ``mean_i dhat_i``.
 
-Grid layout ``(n_workers, m_tiles)``: the TPU grid is sequential, so the
-kernel revisits each output tile once per worker and accumulates in place
-(``out += unpack(packed_i) * scale_i``), initialising on the first visit with
-``pl.when``.  Peak VMEM per step is one packed tile (``TILE_M * B/4`` bytes),
-one scales column and the f32 accumulator tile — the dense per-worker payload
-is never materialised in HBM, which is the whole point: HBM traffic is
-``n * d/4`` bytes in, ``4d`` bytes out, instead of the ``n * 4d`` a naive
-unpack-then-sum would move.
+Grid layout ``(m_tiles, n_workers)``, declared ``("parallel", "arbitrary")``:
+the worker (reduction) axis is innermost, so each output tile stays resident
+in VMEM while every worker's payload for it is added in place
+(``out += unpack(packed_i) * scale_i``, initialised on the first worker with
+``pl.when``) and is written back once, when the tile index moves on.  Peak
+VMEM per step is one packed tile (``TILE_M * B/4`` bytes), one scales column
+and the f32 accumulator tile — the dense per-worker payload is never
+materialised in HBM, which is the whole point: HBM traffic is ``n * d/4``
+bytes in, ``4d`` bytes out, instead of the ``n * 4d`` a naive unpack-then-sum
+would move.
 """
 
 from __future__ import annotations
@@ -18,7 +20,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.packing import unpack2bit
 from repro.core.quantization import pad_axis_to_multiple
 
 __all__ = [
@@ -26,39 +30,29 @@ __all__ = [
     "unpack_reduce_mean",
     "unpack_reduce_apply",
     "DEFAULT_TILE_M",
+    "DECODE_PARAMS",
 ]
 
 DEFAULT_TILE_M = 8
 
-
-def _unpack_dense(packed):
-    """(TILE_M, B/4) u8 -> (TILE_M, B) f32 in {-1, 0, +1}.
-
-    Unpack with unrolled shifts (no captured constant arrays in Pallas).
-    """
-    parts = [
-        ((packed >> jnp.uint8(s)) & jnp.uint8(3)).astype(jnp.int8) - 1
-        for s in (0, 2, 4, 6)
-    ]
-    g = jnp.stack(parts, axis=-1)                             # (TILE_M, B/4, 4)
-    return g.reshape(packed.shape[0], -1).astype(jnp.float32)
+# Output tiles are independent; the worker axis revisits one output block.
+DECODE_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"))
 
 
 def _kernel(packed_ref, scales_ref, out_ref):
-    i = pl.program_id(0)  # worker index
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(1) == 0)  # first worker
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    dense = _unpack_dense(packed_ref[0])                      # (TILE_M, B)
+    dense = unpack2bit(packed_ref[0], dtype=jnp.float32)       # (TILE_M, B)
     out_ref[...] += dense * scales_ref[0].astype(jnp.float32)
 
 
 def _kernel_mean(packed_ref, scales_ref, out_ref, *, n):
     _kernel(packed_ref, scales_ref, out_ref)
 
-    @pl.when(pl.program_id(0) == n - 1)
+    @pl.when(pl.program_id(1) == n - 1)
     def _mean():
         out_ref[...] = out_ref[...] / jnp.float32(n)
 
@@ -69,12 +63,19 @@ def _kernel_apply(packed_ref, scales_ref, h_ref, ghat_ref, newh_ref, *, n, alpha
     # The aggregated sum never round-trips HBM between decode and apply.
     _kernel(packed_ref, scales_ref, ghat_ref)
 
-    @pl.when(pl.program_id(0) == n - 1)
+    @pl.when(pl.program_id(1) == n - 1)
     def _apply():
         dm = ghat_ref[...] / jnp.float32(n)
         h = h_ref[...]
         ghat_ref[...] = h + dm
         newh_ref[...] = h + jnp.float32(alpha) * dm
+
+
+def _payload_specs(tile_m, b4):
+    return [
+        pl.BlockSpec((1, tile_m, b4), lambda j, i: (i, j, 0)),
+        pl.BlockSpec((1, tile_m, 1), lambda j, i: (i, j, 0)),
+    ]
 
 
 @functools.partial(jax.jit, static_argnames=("tile_m", "interpret"))
@@ -91,16 +92,13 @@ def unpack_reduce(
     scales = pad_axis_to_multiple(scales, tile_m, axis=1)
     mp = packed.shape[1]
 
-    grid = (n, mp // tile_m)
     out = pl.pallas_call(
         _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, tile_m, b4), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, tile_m, 1), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile_m, b4 * 4), lambda i, j: (j, 0)),
+        grid=(mp // tile_m, n),
+        in_specs=_payload_specs(tile_m, b4),
+        out_specs=pl.BlockSpec((tile_m, b4 * 4), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, b4 * 4), jnp.float32),
+        compiler_params=DECODE_PARAMS,
         interpret=interpret,
     )(packed, scales)
     return out[:m]
@@ -122,13 +120,11 @@ def unpack_reduce_mean(
 
     out = pl.pallas_call(
         functools.partial(_kernel_mean, n=n),
-        grid=(n, mp // tile_m),
-        in_specs=[
-            pl.BlockSpec((1, tile_m, b4), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, tile_m, 1), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile_m, b4 * 4), lambda i, j: (j, 0)),
+        grid=(mp // tile_m, n),
+        in_specs=_payload_specs(tile_m, b4),
+        out_specs=pl.BlockSpec((tile_m, b4 * 4), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, b4 * 4), jnp.float32),
+        compiler_params=DECODE_PARAMS,
         interpret=interpret,
     )(packed, scales)
     return out[:m]
@@ -163,20 +159,19 @@ def unpack_reduce_apply(
 
     ghat, newh = pl.pallas_call(
         functools.partial(_kernel_apply, n=n, alpha=float(alpha)),
-        grid=(n, mp // tile_m),
-        in_specs=[
-            pl.BlockSpec((1, tile_m, b4), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, tile_m, 1), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((tile_m, b), lambda i, j: (j, 0)),
+        grid=(mp // tile_m, n),
+        in_specs=_payload_specs(tile_m, b4) + [
+            pl.BlockSpec((tile_m, b), lambda j, i: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tile_m, b), lambda i, j: (j, 0)),
-            pl.BlockSpec((tile_m, b), lambda i, j: (j, 0)),
+            pl.BlockSpec((tile_m, b), lambda j, i: (j, 0)),
+            pl.BlockSpec((tile_m, b), lambda j, i: (j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((mp, b), jnp.float32),
             jax.ShapeDtypeStruct((mp, b), jnp.float32),
         ],
+        compiler_params=DECODE_PARAMS,
         interpret=interpret,
     )(packed, scales, h2)
     return ghat.reshape(-1)[:d], newh.reshape(-1)[:d]

@@ -3,6 +3,10 @@
 Defined as FUNCTIONS (never module-level constants) so importing this module
 never touches jax device state — the dry-run must set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first init.
+
+Every mesh is built with ``AxisType.Auto`` axes: the model code places
+activations with ``with_sharding_constraint`` (models/sharding.py), which only
+accepts Auto axes, while ``jax.make_mesh`` defaults to Explicit ones.
 """
 
 from __future__ import annotations
@@ -11,6 +15,7 @@ import math
 from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh", "data_axes", "worker_count", "worker_index"]
 
@@ -19,12 +24,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 (256 chips / pod) single-pod mesh, or 2x16x16 = 512-chip two-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
     """Arbitrary test mesh, e.g. ((2,2,2), ('pod','data','model'))."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh) -> Tuple[str, ...]:
@@ -72,7 +78,9 @@ def resolve_train_mesh(mesh, worker_axes: Sequence[str]):
         n_w *= mesh.shape[a]
     new_shape = (n_w,) + tuple(mesh.shape[a] for a in other)
     devices = mesh.devices.reshape(new_shape)
-    flat = jax.sharding.Mesh(devices, ("data",) + other)
+    names = ("data",) + other
+    flat = jax.sharding.Mesh(devices, names,
+                             axis_types=(AxisType.Auto,) * len(names))
     return flat, ("data",)
 
 
@@ -80,9 +88,7 @@ def worker_index(worker_axes: Sequence[str]):
     """Linearised worker index inside a shard_map body (row-major)."""
     import jax.numpy as jnp
 
-    from repro.compat import axis_size
-
     idx = jnp.zeros((), jnp.int32)
     for a in worker_axes:
-        idx = idx * axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx

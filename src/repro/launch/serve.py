@@ -99,6 +99,9 @@ def main(argv=None):
     from repro.configs import reduced as make_reduced
     from repro.configs.base import ShapeConfig
 
+    from .compile_cache import use_compile_cache
+
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = make_reduced(cfg)

@@ -55,7 +55,7 @@ from .mesh import (
 from .sharding_rules import batch_specs, param_specs
 
 __all__ = ["build_train_step", "train_state_shardings", "init_train_state", "make_optimizer",
-           "resolve_bucketed", "resolved_layout", "resolve_policy_arg"]
+           "resolve_bucketed", "resolved_layout", "resolve_policy_arg", "TrainRun"]
 
 
 def resolve_bucketed(opt: "DianaOptimizer", mesh, waxes) -> "DianaOptimizer":
@@ -398,14 +398,20 @@ def build_train_step(cfg, opt: DianaOptimizer, mesh, shape=None, *, window: Opti
     daxes = data_axes(mesh)
     wtuple = waxes if len(waxes) != 1 else waxes[0]
 
-    inner_axes = tuple(a for a in mesh.axis_names if a not in waxes)
+    # Inner axes of size 1 join the manual set: on a pure worker mesh the
+    # body is then fully manual, which Pallas kernels need (a Mosaic call
+    # cannot be partitioned automatically).
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    manual = tuple(waxes) + tuple(a for a in mesh.axis_names
+                                  if a not in waxes and sizes[a] == 1)
+    inner_axes = tuple(a for a in mesh.axis_names if a not in manual)
     fsdp = tuple(a for a in daxes if a not in waxes)
 
     def local_step(params, opt_state, batch, key, widx):
         # widx: (1,) int32 — this worker's linear index, fed in as sharded
         # data rather than computed via axis_index (which lowers to an
         # unpartitionable PartitionId under partial-manual on old XLA).
-        policy = GSPMDPolicy(mesh, manual=waxes)
+        policy = GSPMDPolicy(mesh, manual=manual)
         with sharding_policy(policy):
             loss_fn = lambda p: train_loss(p, batch, cfg, window=window)
             loss, grads = jax.value_and_grad(loss_fn)(params)
@@ -558,7 +564,7 @@ def build_train_step(cfg, opt: DianaOptimizer, mesh, shape=None, *, window: Opti
             mesh=mesh,
             in_specs=in_specs,
             out_specs=out_specs,
-            axis_names=set(waxes),
+            axis_names=set(manual),
             check_vma=False,
         )
         return fn(params, opt_state, batch, key,
@@ -692,6 +698,9 @@ def main(argv=None):
     ap.add_argument("--reduced", action="store_true", help="toy config for CPU runs")
     ap.add_argument("--batch", type=int, default=None, help="override global batch")
     ap.add_argument("--seq", type=int, default=None, help="override sequence length")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seeds the weights, the synthetic batches and the "
+                         "compression draws")
     ap.add_argument("--checkpoint-dir", default=None)
     args = ap.parse_args(argv)
 
@@ -701,6 +710,9 @@ def main(argv=None):
     from repro.configs.base import ShapeConfig
     from repro.data import make_lm_batch
 
+    from .compile_cache import use_compile_cache
+
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = make_reduced(cfg)
@@ -794,7 +806,7 @@ def main(argv=None):
         if args.warmup_dense_steps > 0:
             opt = opt.replace(policy=controller.warmup_policy())
 
-    key = jax.random.PRNGKey(0)
+    key = jax.random.PRNGKey(args.seed)
     params, opt_state, _ = init_train_state(cfg, opt, mesh, key)
     step_fn = build_train_step(cfg, opt, mesh, shape, faults=faults,
                                telemetry=controller is not None)
@@ -808,19 +820,31 @@ def main(argv=None):
         cstate = init_controller_state(controller, params)
         step_cache[opt.policy] = (opt, step_fn)
 
-    from repro.launch.sharding_rules import batch_specs as bspecs
+    def device_batch(step):
+        host_batch = make_lm_batch(cfg, shape, step, seed=args.seed)
+        return jax.tree_util.tree_map(
+            lambda a, s: jax.device_put(a, NamedSharding(smesh, s)),
+            host_batch, batch_specs(host_batch, smesh))
 
+    # Compile before the first step, so that compilation is set-up time and
+    # every step below runs the already compiled program.
+    t0 = time.perf_counter()
+    compiled = step_fn.lower(params, opt_state, device_batch(0),
+                             jax.random.fold_in(key, 0)).compile()
+    compile_s = time.perf_counter() - t0
+    print(f"compiled the step in {compile_s:.2f}s")
+
+    losses, ghat_norms, step_s = [], [], []
     for step in range(args.steps):
-        host_batch = make_lm_batch(cfg, shape, step)
-        bs = bspecs(host_batch, smesh)
-        batch = jax.tree_util.tree_map(
-            lambda a, s: jax.device_put(a, NamedSharding(smesh, s)), host_batch, bs
-        )
+        batch = device_batch(step)
         t0 = time.perf_counter()
         params, opt_state, metrics = step_fn(params, opt_state, batch, jax.random.fold_in(key, step))
-        loss = float(metrics["loss"])
-        print(f"step {step:4d} loss {loss:8.4f} ghat {float(metrics['ghat_norm']):9.4f} "
-              f"({time.perf_counter() - t0:5.2f}s)")
+        jax.block_until_ready((params, opt_state, metrics))
+        step_s.append(time.perf_counter() - t0)
+        losses.append(float(metrics["loss"]))
+        ghat_norms.append(float(metrics["ghat_norm"]))
+        print(f"step {step:4d} loss {losses[-1]:8.4f} ghat {ghat_norms[-1]:9.4f} "
+              f"({step_s[-1]:5.2f}s)")
 
         if controller is not None:
             opt, opt_state, step_fn, cstate = _controller_tick(
@@ -844,6 +868,19 @@ def main(argv=None):
         save_checkpoint(args.checkpoint_dir, args.steps, {"params": params},
                         metadata=metadata)
         print(f"checkpoint written to {args.checkpoint_dir}")
+    return TrainRun(compiled, compile_s, losses, ghat_norms, step_s)
+
+
+class TrainRun(NamedTuple):
+    """What :func:`main` ran: the compiled first-step program, its compile
+    time, and per step the loss, the served direction's norm and the wall
+    time (host clock, after ``block_until_ready``)."""
+
+    compiled: Any
+    compile_s: float
+    losses: list
+    ghat_norms: list
+    step_s: list
 
 
 def _controller_tick(cfg, controller, cstate, opt, opt_state, step_fn,

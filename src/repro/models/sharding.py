@@ -85,14 +85,14 @@ class GSPMDPolicy(_Policy):
         # Inside a shard_map body the constraint must reference the tracing
         # context's ABSTRACT mesh (whose manual axes carry Manual axis types);
         # the concrete mesh is only valid at the jit boundary.
-        mesh = self.mesh
-        try:
-            amesh = jax.sharding.get_abstract_mesh()
-            if amesh is not None and not amesh.empty:
-                mesh = amesh
-        except Exception:
-            pass
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+        return jax.lax.with_sharding_constraint(
+            x, NamedSharding(_context_mesh(self.mesh), spec))
+
+
+def _context_mesh(mesh):
+    """The tracing context's abstract mesh when there is one, else ``mesh``."""
+    amesh = jax.sharding.get_abstract_mesh()
+    return mesh if amesh.empty else amesh
 
 
 _tls = threading.local()
@@ -127,13 +127,7 @@ def shard_forced(x, *logical):
         return x
     spec = policy.spec(*logical)
     full = P(*(tuple(spec) + (None,) * (x.ndim - len(spec))))
-    mesh = policy.mesh
-    try:
-        amesh = jax.sharding.get_abstract_mesh()
-        if amesh is not None and not amesh.empty:
-            mesh = amesh
-    except Exception:
-        pass
+    mesh = _context_mesh(policy.mesh)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, full))
 
 
@@ -145,13 +139,7 @@ def shard_replicated(x):
     policy = current_policy()
     if not isinstance(policy, GSPMDPolicy):
         return x
-    mesh = policy.mesh
-    try:
-        amesh = jax.sharding.get_abstract_mesh()
-        if amesh is not None and not amesh.empty:
-            mesh = amesh
-    except Exception:
-        pass
+    mesh = _context_mesh(policy.mesh)
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, P(*((None,) * x.ndim)))
     )

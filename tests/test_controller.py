@@ -169,6 +169,8 @@ def run_py(code: str, timeout=900) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    # The trainer CLI turns on the checkout's compile cache; tests write none.
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=timeout, env=env, cwd=REPO)
     assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr[-3000:]}"

@@ -118,3 +118,14 @@ def test_block_padding_roundtrip():
     q = quantize_blocks(x, KEY, p=2, block_size=32)
     y = dequantize_blocks(q, shape=(7, 13))
     assert y.shape == (7, 13)
+
+
+def test_pow2_is_exact_over_the_whole_f32_range():
+    """Natural compression decodes through ``pow2``: every integer exponent
+    from below the subnormals to past overflow gives numpy's exact value."""
+    from repro.core.quantization import pow2
+
+    e = np.arange(-160, 140, dtype=np.int32)
+    with np.errstate(over="ignore"):
+        want = np.ldexp(np.float32(1.0), e).astype(np.float32)
+    np.testing.assert_array_equal(np.asarray(jax.jit(pow2)(jnp.asarray(e))), want)

@@ -1,0 +1,166 @@
+"""Compile every TPU-route Pallas kernel for a described TPU v5e.
+
+Interpret mode (the CPU suite) cannot see what Mosaic refuses: casts it does
+not lower, 8-bit vector math, lane-splitting reshapes, unaligned blocks.
+These tests compile each kernel the TPU backend routes through
+(``interpret=False``) for one chip of a v5e topology that is described, not
+attached, at the widths ``mamba2-130m`` trains with: its whole bucketed
+parameter buffer, ternary blocks of 1024 lanes, and 4 stacked worker rows, so
+every decode grid has many tiles and several workers.  Only shapes are
+passed; nothing runs.
+
+The topology is described inside a module fixture (never at import): only
+one process at a time may load the TPU compiler library, and every test
+worker imports this file.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.nat_pack import (
+    LANES, nat_decode_sum, nat_decode_sum_apply, nat_decode_sum_mean, nat_pack,
+    nat_pack_prng,
+)
+from repro.kernels.quantize_pack import quantize_pack, quantize_pack_prng
+from repro.kernels.unpack_reduce import (
+    unpack_reduce, unpack_reduce_apply, unpack_reduce_mean,
+)
+
+N_WORKERS = 4
+BLOCK = 1024          # mamba2-130m's ternary block (ModelConfig.comp_block)
+ALPHA = 0.25
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler library here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def flat_size():
+    """Padded length of mamba2-130m's bucketed DIANA buffer (block 1024)."""
+    from repro.configs import get_config
+    from repro.core import CompressionConfig
+    from repro.core.diana import bucket_layout
+    from repro.models import init_model
+
+    cfg = get_config("mamba2-130m")
+    params = jax.eval_shape(lambda k: init_model(cfg, k),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    comp = CompressionConfig(method="diana", block_size=BLOCK)
+    return bucket_layout(comp, params).padded_size
+
+
+def _compiled_text(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _ternary(d):
+    m = d // BLOCK
+    return {
+        "quantize_pack": (
+            functools.partial(quantize_pack, p=float("inf"), interpret=False),
+            [((m, BLOCK), jnp.float32), ((m, BLOCK), jnp.uint32)]),
+        "quantize_pack_prng": (
+            functools.partial(quantize_pack_prng, p=float("inf")),
+            [((m, BLOCK), jnp.float32), ((2,), jnp.int32)]),
+        "unpack_reduce": (
+            functools.partial(unpack_reduce, interpret=False),
+            [((N_WORKERS, m, BLOCK // 4), jnp.uint8),
+             ((N_WORKERS, m, 1), jnp.float32)]),
+        "unpack_reduce_mean": (
+            functools.partial(unpack_reduce_mean, interpret=False),
+            [((N_WORKERS, m, BLOCK // 4), jnp.uint8),
+             ((N_WORKERS, m, 1), jnp.float32)]),
+        "unpack_reduce_apply": (
+            functools.partial(unpack_reduce_apply, alpha=ALPHA,
+                              interpret=False),
+            [((N_WORKERS, m, BLOCK // 4), jnp.uint8),
+             ((N_WORKERS, m, 1), jnp.float32), ((d,), jnp.float32)]),
+    }
+
+
+def _natural(d):
+    return {
+        "nat_pack": (
+            functools.partial(nat_pack, interpret=False),
+            [((d,), jnp.float32), ((d,), jnp.uint32)]),
+        "nat_pack_prng": (
+            nat_pack_prng, [((d,), jnp.float32), ((2,), jnp.int32)]),
+        "nat_decode_sum": (
+            functools.partial(nat_decode_sum, interpret=False),
+            [((N_WORKERS, d), jnp.int16)]),
+        "nat_decode_sum_mean": (
+            functools.partial(nat_decode_sum_mean, interpret=False),
+            [((N_WORKERS, d), jnp.int16)]),
+        "nat_decode_sum_apply": (
+            functools.partial(nat_decode_sum_apply, alpha=ALPHA,
+                              interpret=False),
+            [((N_WORKERS, d), jnp.int16), ((d,), jnp.float32)]),
+    }
+
+
+KERNELS = [*_ternary(BLOCK), *_natural(BLOCK)]
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_kernel_compiles_for_v5e(name, one_chip, flat_size):
+    fn, shapes = {**_ternary(flat_size), **_natural(flat_size)}[name]
+    assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
+
+
+def _pallas_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", None)
+            if inner is not None:
+                yield from _pallas_eqns(inner)
+
+
+_S = jax.ShapeDtypeStruct
+_TERNARY_PAYLOAD = (_S((3, 16, 32), jnp.uint8), _S((3, 16, 1), jnp.float32))
+_NAT_CODES = (_S((3, 16 * LANES), jnp.int16),)
+DECODES = {
+    "unpack_reduce": (unpack_reduce, _TERNARY_PAYLOAD),
+    "unpack_reduce_mean": (unpack_reduce_mean, _TERNARY_PAYLOAD),
+    "unpack_reduce_apply": (
+        functools.partial(unpack_reduce_apply, alpha=ALPHA),
+        (*_TERNARY_PAYLOAD, _S((16 * 128,), jnp.float32))),
+    "nat_decode_sum": (nat_decode_sum, _NAT_CODES),
+    "nat_decode_sum_mean": (nat_decode_sum_mean, _NAT_CODES),
+    "nat_decode_sum_apply": (
+        functools.partial(nat_decode_sum_apply, alpha=ALPHA),
+        (*_NAT_CODES, _S((16 * LANES,), jnp.float32))),
+}
+
+
+@pytest.mark.parametrize("name", DECODES)
+def test_decode_grid_reduces_over_workers_last(name):
+    """The worker axis is the innermost grid axis and declared arbitrary, so
+    compiled Pallas keeps each output tile resident while it sums workers
+    (an outer worker axis would write a tile back before the next worker
+    adds to it, and never reload it)."""
+    kernel, args = DECODES[name]
+    jaxpr = jax.make_jaxpr(functools.partial(kernel, tile_m=8))(*args)
+    (eqn,) = _pallas_eqns(jaxpr.jaxpr)
+    assert eqn.params["grid_mapping"].grid == (2, 3)     # (m_tiles, workers)
+    params = eqn.params["compiler_params"]["mosaic_tpu"]
+    assert params.dimension_semantics == ("parallel", "arbitrary")
