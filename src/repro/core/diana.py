@@ -24,6 +24,13 @@ the hooks are Algorithm 1 lines 5-9:
     h_i^{k+1} = h_i^k + alpha * dhat_i^k
     h^{k+1}   = h^k   + alpha * mean_i dhat_i^k
     ghat^k    = h^k + mean_i dhat_i^k
+
+Every path names its passes with ``jax.named_scope`` (metadata only, read
+by the profiler trace): ``diana.round`` around a round, and inside it
+``diana.flatten``, ``diana.encode`` (compress_input + compress),
+``diana.decode_own``, ``diana.allgather``, ``diana.decode_sum_apply``,
+``diana.memory`` and ``diana.unflatten``; ``diana.downlink`` around the
+server broadcast.
 """
 
 from __future__ import annotations
@@ -308,6 +315,7 @@ def init_state(params, cfg, n_workers: int) -> DianaState:
 # Distributed aggregation (inside shard_map over worker axes)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("diana.allgather")
 def _gather_field(a, axis_names, groups=None):
     """All-gather ONE payload field over the worker axes.
 
@@ -362,20 +370,22 @@ def _gathered_sum(payload_tree, like, n_workers: int, axis_names,
     pay_leaves = jax.tree_util.tree_leaves(gathered, is_leaf=_is_payload)
 
     outs = []
-    for pay, l in zip(pay_leaves, like_leaves):
-        if mask is not None:
-            pay = pay.mask_workers(mask)
-        outs.append(comp.decode_sum(pay, n_workers, l.size))
+    with jax.named_scope("diana.decode_sum_apply"):
+        for pay, l in zip(pay_leaves, like_leaves):
+            if mask is not None:
+                pay = pay.mask_workers(mask)
+            outs.append(comp.decode_sum(pay, n_workers, l.size))
     return jax.tree_util.tree_unflatten(treedef, outs)
 
 
 def _gathered_mean(payload_tree, like, n_workers: int, axis_names, comp: Compressor):
     """mean_i decode(payload_i), shaped/typed like ``like``."""
     totals = _gathered_sum(payload_tree, like, n_workers, axis_names, comp)
-    return jax.tree_util.tree_map(
-        lambda t, l: (t / n_workers).reshape(l.shape).astype(l.dtype),
-        totals, like,
-    )
+    with jax.named_scope("diana.decode_sum_apply"):
+        return jax.tree_util.tree_map(
+            lambda t, l: (t / n_workers).reshape(l.shape).astype(l.dtype),
+            totals, like,
+        )
 
 
 def _aggregate_local(grads_local, h_worker, h_server, key, cfg, axis_names,
@@ -394,83 +404,92 @@ def _aggregate_local(grads_local, h_worker, h_server, key, cfg, axis_names,
     """
     comp = cfg.make()
 
-    g_flat = jax.tree_util.tree_map(
-        lambda g: g.reshape(-1).astype(jnp.float32), grads_local
-    )
-    h_local = jax.tree_util.tree_map(
-        lambda h: h[0].astype(jnp.float32), h_worker
-    )
-    if part is not None:
-        h_local = _reinit_zero(part.reinit_own, h_local)
+    with jax.named_scope("diana.flatten"):
+        g_flat = jax.tree_util.tree_map(
+            lambda g: g.reshape(-1).astype(jnp.float32), grads_local
+        )
 
-    delta = jax.tree_util.tree_map(comp.compress_input, g_flat, h_local)
-    if comp.replicate_perleaf:
-        # Pin the encode input replicated: sort-selection operators (top-k)
-        # RET_CHECK old XLA's partitioner on sharded operands under manual
-        # subgroups.  No-op outside GSPMD policies (nested-manual/reference).
-        from repro.models.sharding import shard_replicated
+    with jax.named_scope("diana.encode"):
+        h_local = jax.tree_util.tree_map(
+            lambda h: h[0].astype(jnp.float32), h_worker
+        )
+        if part is not None:
+            h_local = _reinit_zero(part.reinit_own, h_local)
+        delta = jax.tree_util.tree_map(comp.compress_input, g_flat, h_local)
+        if comp.replicate_perleaf:
+            # Pin the encode input replicated: sort-selection operators (top-k)
+            # RET_CHECK old XLA's partitioner on sharded operands under manual
+            # subgroups.  No-op outside GSPMD policies (nested-manual/reference).
+            from repro.models.sharding import shard_replicated
 
-        delta = jax.tree_util.tree_map(shard_replicated, delta)
+            delta = jax.tree_util.tree_map(shard_replicated, delta)
 
-    leaves, treedef = jax.tree_util.tree_flatten(delta)
-    keys = jax.random.split(key, len(leaves))
-    payloads = [comp.compress(leaf, k) for leaf, k in zip(leaves, keys)]
-    payload_tree = jax.tree_util.tree_unflatten(treedef, payloads)
+        leaves, treedef = jax.tree_util.tree_flatten(delta)
+        keys = jax.random.split(key, len(leaves))
+        payloads = [comp.compress(leaf, k) for leaf, k in zip(leaves, keys)]
+        payload_tree = jax.tree_util.tree_unflatten(treedef, payloads)
     # The worker's own estimate, for its memory update — decoded from the
     # payload (bitwise the transmitted value); dead-code-eliminated under jit
     # for operators whose hooks ignore it.
-    dhat_own = jax.tree_util.tree_unflatten(
-        treedef, [comp.decode(p, leaf.size) for p, leaf in zip(payloads, leaves)]
-    )
+    with jax.named_scope("diana.decode_own"):
+        dhat_own = jax.tree_util.tree_unflatten(
+            treedef, [comp.decode(p, leaf.size) for p, leaf in zip(payloads, leaves)]
+        )
 
     if part is None:
         dhat_mean = _gathered_mean(payload_tree, g_flat, n_workers, axis_names, comp)
 
-        new_h_local = jax.tree_util.tree_map(
-            lambda h, dh, dl: comp.next_memory(h, dh, dl).astype(cfg.h_dtype),
-            h_local, dhat_own, delta,
-        )
-        new_hw = jax.tree_util.tree_map(lambda h: h[None], new_h_local)
-        new_h_server = jax.tree_util.tree_map(
-            lambda h, dm: comp.next_server_memory(h.astype(jnp.float32), dm).astype(cfg.h_dtype),
-            h_server, dhat_mean,
-        )
-        ghat_flat = jax.tree_util.tree_map(
-            lambda h, dm: comp.server_direction(h.astype(jnp.float32), dm),
-            h_server, dhat_mean,
-        )
+        with jax.named_scope("diana.memory"):
+            new_h_local = jax.tree_util.tree_map(
+                lambda h, dh, dl: comp.next_memory(h, dh, dl).astype(cfg.h_dtype),
+                h_local, dhat_own, delta,
+            )
+            new_hw = jax.tree_util.tree_map(lambda h: h[None], new_h_local)
+        with jax.named_scope("diana.decode_sum_apply"):
+            new_h_server = jax.tree_util.tree_map(
+                lambda h, dm: comp.next_server_memory(h.astype(jnp.float32), dm).astype(cfg.h_dtype),
+                h_server, dhat_mean,
+            )
+            ghat_flat = jax.tree_util.tree_map(
+                lambda h, dm: comp.server_direction(h.astype(jnp.float32), dm),
+                h_server, dhat_mean,
+            )
     else:
         # Sampled sum: per-leaf payloads carry no wire checksum, so the
         # effective set is the scheduled mask itself.
         totals = _gathered_sum(payload_tree, g_flat, n_workers, axis_names,
                                comp, mask=part.mask)
-        hs_leaves, hs_def = jax.tree_util.tree_flatten(h_server)
-        served = [
-            _masked_server_tail(comp, h.astype(jnp.float32), t, n_workers,
-                                part, part.mask)
-            for h, t in zip(hs_leaves, jax.tree_util.tree_leaves(totals))
-        ]
-        ghat_flat = jax.tree_util.tree_unflatten(hs_def, [g for g, _ in served])
-        new_h_server = jax.tree_util.tree_unflatten(
-            hs_def, [h.astype(cfg.h_dtype) for _, h in served])
-        advance = part.m_own & part.ok
-        new_h_local = _where_rows(
-            advance,
-            jax.tree_util.tree_map(comp.next_memory, h_local, dhat_own, delta),
-            h_local,
-        )
-        new_hw = jax.tree_util.tree_map(
-            lambda h: h.astype(cfg.h_dtype)[None], new_h_local)
+        with jax.named_scope("diana.decode_sum_apply"):
+            hs_leaves, hs_def = jax.tree_util.tree_flatten(h_server)
+            served = [
+                _masked_server_tail(comp, h.astype(jnp.float32), t, n_workers,
+                                    part, part.mask)
+                for h, t in zip(hs_leaves, jax.tree_util.tree_leaves(totals))
+            ]
+            ghat_flat = jax.tree_util.tree_unflatten(hs_def, [g for g, _ in served])
+            new_h_server = jax.tree_util.tree_unflatten(
+                hs_def, [h.astype(cfg.h_dtype) for _, h in served])
+        with jax.named_scope("diana.memory"):
+            advance = part.m_own & part.ok
+            new_h_local = _where_rows(
+                advance,
+                jax.tree_util.tree_map(comp.next_memory, h_local, dhat_own, delta),
+                h_local,
+            )
+            new_hw = jax.tree_util.tree_map(
+                lambda h: h.astype(cfg.h_dtype)[None], new_h_local)
 
     # Reshape only — ghat stays f32; the caller casts to the gradient dtypes
     # AFTER the (optional) downlink round, so the downlink compresses the
     # same f32 server direction the reference path sees.
-    ghat = jax.tree_util.tree_map(
-        lambda f, g: f.reshape(g.shape), ghat_flat, grads_local
-    )
+    with jax.named_scope("diana.unflatten"):
+        ghat = jax.tree_util.tree_map(
+            lambda f, g: f.reshape(g.shape), ghat_flat, grads_local
+        )
     return ghat, new_hw, new_h_server
 
 
+@jax.named_scope("diana.allgather")
 def _gather_fused(payload: Payload, axis_names, groups=None):
     """All-gather ONE fused uint8 buffer instead of one collective per field.
 
@@ -567,16 +586,18 @@ def _chunk_payloads(cfg, sched: ChunkedSchedule, delta, key):
     in-kernel-PRNG encodes (distribution-equal mode — see CHUNK_FOLD).
     """
     base = cfg.make()
-    keys = jax.random.split(key, sched.layout.n_leaves)
-    return [
-        base.compress_bucketed_keys(
-            cl, dseg, sched.chunk_keys(keys, c),
-            jax.random.fold_in(key, CHUNK_FOLD + c))
-        for c, (cl, dseg) in enumerate(
-            zip(sched.chunk_layouts, sched.split(delta)))
-    ]
+    with jax.named_scope("diana.encode"):
+        keys = jax.random.split(key, sched.layout.n_leaves)
+        return [
+            base.compress_bucketed_keys(
+                cl, dseg, sched.chunk_keys(keys, c),
+                jax.random.fold_in(key, CHUNK_FOLD + c))
+            for c, (cl, dseg) in enumerate(
+                zip(sched.chunk_layouts, sched.split(delta)))
+        ]
 
 
+@jax.named_scope("diana.decode_own")
 def _chunk_decode_own(cfg, sched: ChunkedSchedule, pays):
     """This worker's own dhat over the whole buffer: per-chunk decodes
     concatenated (per-coordinate, so bitwise the monolithic decode)."""
@@ -624,7 +645,8 @@ def _aggregate_bucketed(grads_local, h_worker, h_server, key, cfg, axis_names,
     comp = bucketed_compressor(cfg, layout)
     dp = layout.padded_size
 
-    g_flat = layout.flatten(grads_local)                 # (Dp,) f32
+    with jax.named_scope("diana.flatten"):
+        g_flat = layout.flatten(grads_local)             # (Dp,) f32
     node_size = _hier_node_size(cfg)
     n_eff, groups = n_workers, None
     if node_size > 1:
@@ -635,10 +657,11 @@ def _aggregate_bucketed(grads_local, h_worker, h_server, key, cfg, axis_names,
         n_eff = n_workers // node_size
         groups = _internode_groups(n_workers, node_size)
 
-    h_local = h_worker[0].astype(jnp.float32)            # (Dp,)
-    if part is not None:
-        h_local = jnp.where(part.reinit_own, jnp.zeros_like(h_local), h_local)
-    delta = comp.compress_input(g_flat, h_local)
+    with jax.named_scope("diana.encode"):
+        h_local = h_worker[0].astype(jnp.float32)        # (Dp,)
+        if part is not None:
+            h_local = jnp.where(part.reinit_own, jnp.zeros_like(h_local), h_local)
+        delta = comp.compress_input(g_flat, h_local)
 
     sched = ChunkedSchedule.for_layout(layout, cfg.chunk_bytes)
     if sched.n_chunks > 1:
@@ -647,8 +670,10 @@ def _aggregate_bucketed(grads_local, h_worker, h_server, key, cfg, axis_names,
             axis_names, n_eff, groups, n_workers,
             part=part, faults=faults, step=step)
 
-    payload = comp.compress(delta, key)                  # ONE Payload
-    dhat_own = comp.decode(payload, dp)
+    with jax.named_scope("diana.encode"):
+        payload = comp.compress(delta, key)              # ONE Payload
+    with jax.named_scope("diana.decode_own"):
+        dhat_own = comp.decode(payload, dp)
 
     if part is None and faults is None:
         gathered = _gather_fused(payload, axis_names, groups)  # ONE collective
@@ -656,14 +681,17 @@ def _aggregate_bucketed(grads_local, h_worker, h_server, key, cfg, axis_names,
         # one hook — ONE kernel launch for kernel-backed operators (the
         # epilogue runs on the accumulator tile), the bitwise-identical hook
         # composition otherwise.
-        ghat_flat, new_hs_f = comp.decode_sum_apply(
-            gathered, n_eff, dp, h_server.astype(jnp.float32)
-        )
-        new_hw = comp.next_memory(h_local, dhat_own, delta).astype(cfg.h_dtype)[None]
-        new_hs = new_hs_f.astype(cfg.h_dtype)
+        with jax.named_scope("diana.decode_sum_apply"):
+            ghat_flat, new_hs_f = comp.decode_sum_apply(
+                gathered, n_eff, dp, h_server.astype(jnp.float32)
+            )
+        with jax.named_scope("diana.memory"):
+            new_hw = comp.next_memory(h_local, dhat_own, delta).astype(cfg.h_dtype)[None]
+            new_hs = new_hs_f.astype(cfg.h_dtype)
         # f32 leaves — the caller casts to the gradient dtypes after the
         # (optional) downlink round, like the per-leaf path.
-        ghat = layout.unflatten(ghat_flat, cast=False)
+        with jax.named_scope("diana.unflatten"):
+            ghat = layout.unflatten(ghat_flat, cast=False)
         return ghat, new_hw, new_hs
 
     valid = None
@@ -678,16 +706,19 @@ def _aggregate_bucketed(grads_local, h_worker, h_server, key, cfg, axis_names,
         gathered = _gather_fused(payload, axis_names)
 
     m_eff = part.mask if valid is None else part.mask & valid
-    total = comp.decode_sum(gathered.mask_workers(m_eff), n_workers, dp)
-    ghat_flat, new_hs_f = _masked_server_tail(
-        comp, h_server.astype(jnp.float32), total, n_workers, part, m_eff)
-    gate = part.m_own & part.ok
-    if valid is not None:
-        gate = gate & jnp.any(valid & (jnp.arange(n_workers) == part.widx))
-    new_h_local = jnp.where(gate, comp.next_memory(h_local, dhat_own, delta),
-                            h_local)
-    return (layout.unflatten(ghat_flat, cast=False),
-            new_h_local.astype(cfg.h_dtype)[None],
+    with jax.named_scope("diana.decode_sum_apply"):
+        total = comp.decode_sum(gathered.mask_workers(m_eff), n_workers, dp)
+        ghat_flat, new_hs_f = _masked_server_tail(
+            comp, h_server.astype(jnp.float32), total, n_workers, part, m_eff)
+    with jax.named_scope("diana.memory"):
+        gate = part.m_own & part.ok
+        if valid is not None:
+            gate = gate & jnp.any(valid & (jnp.arange(n_workers) == part.widx))
+        new_h_local = jnp.where(gate, comp.next_memory(h_local, dhat_own, delta),
+                                h_local)
+    with jax.named_scope("diana.unflatten"):
+        ghat = layout.unflatten(ghat_flat, cast=False)
+    return (ghat, new_h_local.astype(cfg.h_dtype)[None],
             new_hs_f.astype(cfg.h_dtype))
 
 
@@ -736,15 +767,19 @@ def _aggregate_bucketed_chunked(layout, comp, sched, delta, h_local, h_server,
         for c in range(C):
             if c + 1 < C:
                 gathered[c + 1] = _gather_fused(pays[c + 1], axis_names, groups)
-            g_c, h_c = comps[c].decode_sum_apply(
-                gathered[c], n_eff, cls_[c].padded_size, hs_chunks[c])
+            with jax.named_scope("diana.decode_sum_apply"):
+                g_c, h_c = comps[c].decode_sum_apply(
+                    gathered[c], n_eff, cls_[c].padded_size, hs_chunks[c])
             ghat_parts.append(g_c)
             hs_parts.append(h_c)
-        ghat_flat = jnp.concatenate(ghat_parts)
-        new_hs_f = jnp.concatenate(hs_parts)
-        new_hw = comp.next_memory(h_local, dhat_own, delta).astype(cfg.h_dtype)[None]
-        return (layout.unflatten(ghat_flat, cast=False), new_hw,
-                new_hs_f.astype(cfg.h_dtype))
+        with jax.named_scope("diana.decode_sum_apply"):
+            ghat_flat = jnp.concatenate(ghat_parts)
+            new_hs_f = jnp.concatenate(hs_parts)
+        with jax.named_scope("diana.memory"):
+            new_hw = comp.next_memory(h_local, dhat_own, delta).astype(cfg.h_dtype)[None]
+        with jax.named_scope("diana.unflatten"):
+            ghat = layout.unflatten(ghat_flat, cast=False)
+        return ghat, new_hw, new_hs_f.astype(cfg.h_dtype)
 
     valid = None
     if faults is not None:
@@ -778,20 +813,23 @@ def _aggregate_bucketed_chunked(layout, comp, sched, delta, h_local, h_server,
             gathereds[c] = _gather_fused(pays[c], axis_names, groups)
 
     m_eff = part.mask if valid is None else part.mask & valid
-    total = jnp.concatenate([
-        comps[c].decode_sum(gathereds[c].mask_workers(m_eff), n_workers,
-                            cls_[c].padded_size)
-        for c in range(C)
-    ])
-    ghat_flat, new_hs_f = _masked_server_tail(
-        comp, h_s, total, n_workers, part, m_eff)
-    gate = part.m_own & part.ok
-    if valid is not None:
-        gate = gate & jnp.any(valid & (jnp.arange(n_workers) == part.widx))
-    new_h_local = jnp.where(gate, comp.next_memory(h_local, dhat_own, delta),
-                            h_local)
-    return (layout.unflatten(ghat_flat, cast=False),
-            new_h_local.astype(cfg.h_dtype)[None],
+    with jax.named_scope("diana.decode_sum_apply"):
+        total = jnp.concatenate([
+            comps[c].decode_sum(gathereds[c].mask_workers(m_eff), n_workers,
+                                cls_[c].padded_size)
+            for c in range(C)
+        ])
+        ghat_flat, new_hs_f = _masked_server_tail(
+            comp, h_s, total, n_workers, part, m_eff)
+    with jax.named_scope("diana.memory"):
+        gate = part.m_own & part.ok
+        if valid is not None:
+            gate = gate & jnp.any(valid & (jnp.arange(n_workers) == part.widx))
+        new_h_local = jnp.where(gate, comp.next_memory(h_local, dhat_own, delta),
+                                h_local)
+    with jax.named_scope("diana.unflatten"):
+        ghat = layout.unflatten(ghat_flat, cast=False)
+    return (ghat, new_h_local.astype(cfg.h_dtype)[None],
             new_hs_f.astype(cfg.h_dtype))
 
 
@@ -799,6 +837,7 @@ def _aggregate_bucketed_chunked(layout, comp, sched, delta, h_local, h_server,
 # Downlink: the compressed server broadcast (DESIGN.md §Bidirectional)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("diana.downlink")
 def downlink_round(ghat, h_down, down_key: jax.Array, cfg: CompressionConfig,
                    *, h_dtype=None, dcfg=None):
     """Pass the aggregated direction ``ghat`` through the DOWNLINK compressor.
@@ -888,6 +927,7 @@ def downlink_round(ghat, h_down, down_key: jax.Array, cfg: CompressionConfig,
     return ghat_hat, new_h
 
 
+@jax.named_scope("diana.round")
 def aggregate_shardmap(
     grads_local,
     state: DianaState,
@@ -1324,6 +1364,7 @@ def reference_init(params, cfg, n_workers: int) -> ReferenceState:
     )
 
 
+@jax.named_scope("diana.round")
 def reference_step(
     grads_per_worker,
     state: ReferenceState,
@@ -1548,18 +1589,20 @@ def _reference_agg_perleaf(grads_per_worker, h_worker, h_server, key, cfg,
         hw = jax.tree_util.tree_map(
             lambda h: h[w].astype(jnp.float32), h_worker
         )
-        delta = jax.tree_util.tree_map(comp.compress_input, gw, hw)
-
-        leaves, treedef = jax.tree_util.tree_flatten(delta)
-        keys = jax.random.split(_worker_key(key, w, gfold), len(leaves))
-        payloads = [comp.compress(leaf, k) for leaf, k in zip(leaves, keys)]
-        dhat_w = jax.tree_util.tree_unflatten(
-            treedef, [comp.decode(p, leaf.size) for p, leaf in zip(payloads, leaves)]
-        )
+        with jax.named_scope("diana.encode"):
+            delta = jax.tree_util.tree_map(comp.compress_input, gw, hw)
+            leaves, treedef = jax.tree_util.tree_flatten(delta)
+            keys = jax.random.split(_worker_key(key, w, gfold), len(leaves))
+            payloads = [comp.compress(leaf, k) for leaf, k in zip(leaves, keys)]
+        with jax.named_scope("diana.decode_own"):
+            dhat_w = jax.tree_util.tree_unflatten(
+                treedef, [comp.decode(p, leaf.size) for p, leaf in zip(payloads, leaves)]
+            )
         payload_trees.append(jax.tree_util.tree_unflatten(treedef, payloads))
-        new_h_rows.append(jax.tree_util.tree_map(
-            comp.next_memory, hw, dhat_w, delta
-        ))
+        with jax.named_scope("diana.memory"):
+            new_h_rows.append(jax.tree_util.tree_map(
+                comp.next_memory, hw, dhat_w, delta
+            ))
 
     # Stack per-worker payloads into the gathered layout (leading worker axis)
     # and decode through the same summation path as the distributed server.
@@ -1572,10 +1615,11 @@ def _reference_agg_perleaf(grads_per_worker, h_worker, h_server, key, cfg,
     pay_leaves = jax.tree_util.tree_leaves(stacked, is_leaf=_is_payload)
     hs_leaves = jax.tree_util.tree_leaves(h_server)
     if part is None:
-        served = [
-            comp.decode_sum_apply(pay, n, l.size, hs)
-            for pay, l, hs in zip(pay_leaves, like_leaves, hs_leaves)
-        ]
+        with jax.named_scope("diana.decode_sum_apply"):
+            served = [
+                comp.decode_sum_apply(pay, n, l.size, hs)
+                for pay, l, hs in zip(pay_leaves, like_leaves, hs_leaves)
+            ]
         new_hw = jax.tree_util.tree_map(
             lambda *rows: jnp.stack(rows), *new_h_rows)
     else:
@@ -1676,42 +1720,50 @@ def _reference_agg_bucketed(grads_per_worker, h_worker, h_server, key, cfg,
 
     def worker_round(_, xs):
         w, g_row, h_row = xs
-        flat_g = layout.flatten(g_row)
-        delta = comp.compress_input(flat_g, h_row)
-        wkey = _worker_key(key, w, gfold)
-        if chunked:
-            keys = jax.random.split(wkey, layout.n_leaves)
-            payload = tuple(
-                base.compress_bucketed_keys(
-                    cl, dseg, sched.chunk_keys(keys, c),
-                    jax.random.fold_in(wkey, CHUNK_FOLD + c))
-                for c, (cl, dseg) in enumerate(zip(cls_, sched.split(delta))))
-            dhat_w = jnp.concatenate([
-                comps[c].decode(payload[c], cls_[c].padded_size)
-                for c in range(sched.n_chunks)])
-        else:
-            payload = comp.compress(delta, wkey)
-            dhat_w = comp.decode(payload, dp)
-        return None, (payload, comp.next_memory(h_row, dhat_w, delta))
+        with jax.named_scope("diana.flatten"):
+            flat_g = layout.flatten(g_row)
+        with jax.named_scope("diana.encode"):
+            delta = comp.compress_input(flat_g, h_row)
+            wkey = _worker_key(key, w, gfold)
+            if chunked:
+                keys = jax.random.split(wkey, layout.n_leaves)
+                payload = tuple(
+                    base.compress_bucketed_keys(
+                        cl, dseg, sched.chunk_keys(keys, c),
+                        jax.random.fold_in(wkey, CHUNK_FOLD + c))
+                    for c, (cl, dseg) in enumerate(zip(cls_, sched.split(delta))))
+            else:
+                payload = comp.compress(delta, wkey)
+        with jax.named_scope("diana.decode_own"):
+            if chunked:
+                dhat_w = jnp.concatenate([
+                    comps[c].decode(payload[c], cls_[c].padded_size)
+                    for c in range(sched.n_chunks)])
+            else:
+                dhat_w = comp.decode(payload, dp)
+        with jax.named_scope("diana.memory"):
+            return None, (payload, comp.next_memory(h_row, dhat_w, delta))
 
     _, (stacked, new_h) = jax.lax.scan(
         worker_round, None,
         (jnp.arange(n), grads_per_worker, h_worker),
     )
     if part is None and faults is None:
-        if chunked:
-            hs_chunks = sched.split(h_server)
-            served = [
-                comps[c].decode_sum_apply(stacked[c], n,
-                                          cls_[c].padded_size, hs_chunks[c])
-                for c in range(sched.n_chunks)
-            ]
-            ghat_flat = jnp.concatenate([g for g, _ in served])
-            new_hs = jnp.concatenate([h for _, h in served])
-        else:
-            ghat_flat, new_hs = comp.decode_sum_apply(stacked, n, dp, h_server)
+        with jax.named_scope("diana.decode_sum_apply"):
+            if chunked:
+                hs_chunks = sched.split(h_server)
+                served = [
+                    comps[c].decode_sum_apply(stacked[c], n,
+                                              cls_[c].padded_size, hs_chunks[c])
+                    for c in range(sched.n_chunks)
+                ]
+                ghat_flat = jnp.concatenate([g for g, _ in served])
+                new_hs = jnp.concatenate([h for _, h in served])
+            else:
+                ghat_flat, new_hs = comp.decode_sum_apply(stacked, n, dp, h_server)
         # f32, like the per-leaf ref
-        ghat = layout.unflatten(ghat_flat, cast=False)
+        with jax.named_scope("diana.unflatten"):
+            ghat = layout.unflatten(ghat_flat, cast=False)
         if node_size > 1:
             # Every worker of a node stores the identical node memory row.
             new_h = jnp.repeat(new_h, node_size, axis=0)

@@ -40,6 +40,7 @@ def dense_copy(x: jax.Array, *, interpret: bool = True) -> jax.Array:
     d = x.shape[0]
     return pl.pallas_call(
         _copy_kernel,
+        name="dense_copy",
         grid=(1,),
         in_specs=[pl.BlockSpec((d,), lambda i: (0,))],
         out_specs=pl.BlockSpec((d,), lambda i: (0,)),
@@ -78,6 +79,7 @@ def dense_decode_sum(values: jax.Array, *, interpret: bool = True) -> jax.Array:
     n, d = values.shape
     return pl.pallas_call(
         _sum_kernel,
+        name="dense_decode_sum",
         grid=(n,),
         in_specs=[pl.BlockSpec((1, d), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((d,), lambda i: (0,)),
@@ -94,6 +96,7 @@ def dense_decode_sum_mean(
     n, d = values.shape
     return pl.pallas_call(
         functools.partial(_mean_kernel, n=n),
+        name="dense_decode_sum_mean",
         grid=(n,),
         in_specs=[pl.BlockSpec((1, d), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((d,), lambda i: (0,)),

@@ -119,6 +119,7 @@ def nat_pack(
     mp = x2.shape[0]
     codes = pl.pallas_call(
         _kernel,
+        name="nat_pack",
         grid=(mp // tile_m,),
         in_specs=[
             pl.BlockSpec((tile_m, LANES), lambda i: (i, 0)),
@@ -154,6 +155,7 @@ def nat_pack_prng(
     )
     codes = pl.pallas_call(
         _kernel_prng,
+        name="nat_pack_prng",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mp, LANES), jnp.int16),
     )(seed.astype(jnp.int32), x2)
@@ -233,6 +235,7 @@ def nat_decode_sum(
     n, mp, _ = c.shape
     out = pl.pallas_call(
         _sum_kernel,
+        name="nat_decode_sum",
         grid=(mp // tile_m, n),
         in_specs=[_codes_spec(tile_m)],
         out_specs=pl.BlockSpec((tile_m, LANES), lambda j, i: (j, 0)),
@@ -256,6 +259,7 @@ def nat_decode_sum_mean(
     n, mp, _ = c.shape
     out = pl.pallas_call(
         functools.partial(_mean_kernel, n=n),
+        name="nat_decode_sum_mean",
         grid=(mp // tile_m, n),
         in_specs=[_codes_spec(tile_m)],
         out_specs=pl.BlockSpec((tile_m, LANES), lambda j, i: (j, 0)),
@@ -290,6 +294,7 @@ def nat_decode_sum_apply(
     )
     ghat, newh = pl.pallas_call(
         functools.partial(_apply_kernel, n=n, alpha=float(alpha)),
+        name="nat_decode_sum_apply",
         grid=(mp // tile_m, n),
         in_specs=[
             _codes_spec(tile_m),

@@ -116,6 +116,7 @@ def quantize_pack(
     grid = (mp // tile_m,)
     packed, scales = pl.pallas_call(
         functools.partial(_kernel, p=p),
+        name="quantize_pack",
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile_m, b), lambda i: (i, 0)),
@@ -167,6 +168,7 @@ def quantize_pack_prng(
     )
     packed, scales = pl.pallas_call(
         functools.partial(_kernel_prng, p=p),
+        name="quantize_pack_prng",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((mp, b // 4), jnp.uint8),

@@ -64,6 +64,7 @@ def sparse_gather(
     d, k = x.shape[0], idx.shape[0]
     return pl.pallas_call(
         _gather_kernel,
+        name="sparse_gather",
         grid=(1,),
         in_specs=[
             pl.BlockSpec((d,), lambda i: (0,)),
@@ -130,6 +131,7 @@ def sparse_decode_sum(
     in_specs, out_spec = _sparse_specs(n, k, d)
     return pl.pallas_call(
         _sum_kernel,
+        name="sparse_decode_sum",
         grid=(n,),
         in_specs=in_specs,
         out_specs=out_spec,
@@ -156,6 +158,7 @@ def sparse_decode_sum_mean(
     in_specs, out_spec = _sparse_specs(n, k, d)
     return pl.pallas_call(
         functools.partial(_mean_kernel, n=n),
+        name="sparse_decode_sum_mean",
         grid=(n,),
         in_specs=in_specs,
         out_specs=out_spec,

@@ -94,6 +94,7 @@ def unpack_reduce(
 
     out = pl.pallas_call(
         _kernel,
+        name="unpack_reduce",
         grid=(mp // tile_m, n),
         in_specs=_payload_specs(tile_m, b4),
         out_specs=pl.BlockSpec((tile_m, b4 * 4), lambda j, i: (j, 0)),
@@ -120,6 +121,7 @@ def unpack_reduce_mean(
 
     out = pl.pallas_call(
         functools.partial(_kernel_mean, n=n),
+        name="unpack_reduce_mean",
         grid=(mp // tile_m, n),
         in_specs=_payload_specs(tile_m, b4),
         out_specs=pl.BlockSpec((tile_m, b4 * 4), lambda j, i: (j, 0)),
@@ -159,6 +161,7 @@ def unpack_reduce_apply(
 
     ghat, newh = pl.pallas_call(
         functools.partial(_kernel_apply, n=n, alpha=float(alpha)),
+        name="unpack_reduce_apply",
         grid=(mp // tile_m, n),
         in_specs=_payload_specs(tile_m, b4) + [
             pl.BlockSpec((tile_m, b), lambda j, i: (j, 0)),

@@ -30,6 +30,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.compat import shard_map
@@ -489,10 +490,13 @@ def build_train_step(cfg, opt: DianaOptimizer, mesh, shape=None, *, window: Opti
             else:
                 ghat, new_diana = agg
             if waxes:
-                loss = jax.lax.pmean(loss, waxes)
-            new_params, new_opt = opt.apply_direction(params, ghat, opt_state, new_diana)
-        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                             for g in jax.tree_util.tree_leaves(ghat)))
+                with jax.named_scope("train.metrics"):
+                    loss = jax.lax.pmean(loss, waxes)
+            with jax.named_scope("train.optimizer"):
+                new_params, new_opt = opt.apply_direction(params, ghat, opt_state, new_diana)
+        with jax.named_scope("train.metrics"):
+            gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                                 for g in jax.tree_util.tree_leaves(ghat)))
         metrics = {"loss": loss, "ghat_norm": gnorm, "step": new_opt.step}
         if telemetry:
             metrics["telemetry_m2"] = telem.m2
@@ -702,7 +706,15 @@ def main(argv=None):
                     help="seeds the weights, the synthetic batches and the "
                          "compression draws")
     ap.add_argument("--checkpoint-dir", default=None)
+    ap.add_argument("--profile-dir", default=None,
+                    help="write a JAX profiler trace of the steps that "
+                         "--profile-steps names into this directory "
+                         "(README 'Profiling a training run')")
+    ap.add_argument("--profile-steps", default="1:4",
+                    help="the traced steps as a:b, step a up to but not "
+                         "including step b (used with --profile-dir)")
     args = ap.parse_args(argv)
+    profile = _profile_window(args.profile_dir, args.profile_steps, args.steps)
 
     from dataclasses import replace as dc_replace
 
@@ -834,41 +846,79 @@ def main(argv=None):
     compile_s = time.perf_counter() - t0
     print(f"compiled the step in {compile_s:.2f}s")
 
+    # Host spans for the profiler (``train.*``, one StepTraceAnnotation per
+    # step); they cost nothing and write nothing while no trace is active.
     losses, ghat_norms, step_s = [], [], []
-    for step in range(args.steps):
-        batch = device_batch(step)
-        t0 = time.perf_counter()
-        params, opt_state, metrics = step_fn(params, opt_state, batch, jax.random.fold_in(key, step))
-        jax.block_until_ready((params, opt_state, metrics))
-        step_s.append(time.perf_counter() - t0)
-        losses.append(float(metrics["loss"]))
-        ghat_norms.append(float(metrics["ghat_norm"]))
-        print(f"step {step:4d} loss {losses[-1]:8.4f} ghat {ghat_norms[-1]:9.4f} "
-              f"({step_s[-1]:5.2f}s)")
+    tracing = False
+    try:
+        for step in range(args.steps):
+            if profile is not None and step == profile.start:
+                jax.profiler.start_trace(args.profile_dir)
+                tracing = True
+            if tracing and step == profile.stop:
+                jax.profiler.stop_trace()
+                tracing = False
+            with StepTraceAnnotation("train", step_num=step):
+                with TraceAnnotation("train.feed"):
+                    batch = device_batch(step)
+                t0 = time.perf_counter()
+                with TraceAnnotation("train.step"):
+                    params, opt_state, metrics = step_fn(
+                        params, opt_state, batch, jax.random.fold_in(key, step))
+                with TraceAnnotation("train.block"):
+                    jax.block_until_ready((params, opt_state, metrics))
+                step_s.append(time.perf_counter() - t0)
+                losses.append(float(metrics["loss"]))
+                ghat_norms.append(float(metrics["ghat_norm"]))
+                print(f"step {step:4d} loss {losses[-1]:8.4f} ghat {ghat_norms[-1]:9.4f} "
+                      f"({step_s[-1]:5.2f}s)")
 
-        if controller is not None:
-            opt, opt_state, step_fn, cstate = _controller_tick(
-                cfg, controller, cstate, opt, opt_state, step_fn, metrics,
-                params, mesh, shape, step_cache)
+                if controller is not None:
+                    with TraceAnnotation("train.controller"):
+                        opt, opt_state, step_fn, cstate = _controller_tick(
+                            cfg, controller, cstate, opt, opt_state, step_fn, metrics,
+                            params, mesh, shape, step_cache)
 
-    if args.checkpoint_dir:
-        from repro.checkpoint import save_checkpoint
+        if args.checkpoint_dir:
+            from repro.checkpoint import save_checkpoint
 
-        # The policy rides in the manifest metadata so a restore can rebuild
-        # the matching (possibly grouped) state template without the CLI
-        # args; with the controller on, its state + telemetry EMAs ride
-        # along so a resume keeps the dwell clock and learned statistics
-        # (repro.checkpoint.controller_restore_hint flags pre-controller
-        # checkpoints).
-        metadata = {"policy": opt.policy.to_json_dict()}
-        if controller is not None:
-            from repro.core import controller_metadata
+            # The policy rides in the manifest metadata so a restore can rebuild
+            # the matching (possibly grouped) state template without the CLI
+            # args; with the controller on, its state + telemetry EMAs ride
+            # along so a resume keeps the dwell clock and learned statistics
+            # (repro.checkpoint.controller_restore_hint flags pre-controller
+            # checkpoints).
+            metadata = {"policy": opt.policy.to_json_dict()}
+            if controller is not None:
+                from repro.core import controller_metadata
 
-            metadata["controller"] = controller_metadata(controller, cstate)
-        save_checkpoint(args.checkpoint_dir, args.steps, {"params": params},
-                        metadata=metadata)
-        print(f"checkpoint written to {args.checkpoint_dir}")
+                metadata["controller"] = controller_metadata(controller, cstate)
+            with TraceAnnotation("train.checkpoint"):
+                save_checkpoint(args.checkpoint_dir, args.steps, {"params": params},
+                                metadata=metadata)
+            print(f"checkpoint written to {args.checkpoint_dir}")
+    finally:
+        if tracing:
+            jax.profiler.stop_trace()
+    if profile is not None:
+        print(f"profiler trace of steps {profile.start}-{profile.stop - 1} "
+              f"written under {args.profile_dir}")
     return TrainRun(compiled, compile_s, losses, ghat_norms, step_s)
+
+
+def _profile_window(profile_dir: Optional[str], steps: str,
+                    n_steps: int) -> Optional[range]:
+    """The steps ``--profile-steps a:b`` names (``range(a, b)``), or None
+    without ``--profile-dir``."""
+    if profile_dir is None:
+        return None
+    try:
+        a, b = (int(x) for x in steps.split(":"))
+    except ValueError:
+        raise SystemExit(f"--profile-steps wants a:b, got {steps!r}") from None
+    if not 0 <= a < min(b, n_steps):
+        raise SystemExit(f"--profile-steps {steps}: needs 0 <= a < b and a < --steps")
+    return range(a, min(b, n_steps))
 
 
 class TrainRun(NamedTuple):

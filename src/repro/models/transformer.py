@@ -116,20 +116,22 @@ def _block_apply(bparams, x, cfg, positions, bcaches, window):
     for i, spec in enumerate(cfg.pattern):
         lp = bparams[f"layer{i}"]
         cache_i = bcaches[i] if bcaches is not None else None
-        h = L.rms_norm(lp["norm1"], x, cfg.norm_eps)
-        if spec.mixer == "attn":
-            mix, nc = L.attention(lp["mixer"], h, cfg, positions, cache=cache_i, window=window)
-        else:
-            mix, nc = M.mamba_layer(lp["mixer"], h, cfg, cache=cache_i)
-        x = x + mix
-        if spec.mlp != "none":
-            h2 = L.rms_norm(lp["norm2"], x, cfg.norm_eps)
-            if spec.mlp == "moe":
-                y, a = MOE.moe_layer(lp["mlp"], h2, cfg)
-                aux = aux + a
+        with jax.named_scope("model.mixer"):
+            h = L.rms_norm(lp["norm1"], x, cfg.norm_eps)
+            if spec.mixer == "attn":
+                mix, nc = L.attention(lp["mixer"], h, cfg, positions, cache=cache_i, window=window)
             else:
-                y = L.mlp(lp["mlp"], h2, cfg)
-            x = x + y
+                mix, nc = M.mamba_layer(lp["mixer"], h, cfg, cache=cache_i)
+            x = x + mix
+        if spec.mlp != "none":
+            with jax.named_scope("model.mlp"):
+                h2 = L.rms_norm(lp["norm2"], x, cfg.norm_eps)
+                if spec.mlp == "moe":
+                    y, a = MOE.moe_layer(lp["mlp"], h2, cfg)
+                    aux = aux + a
+                else:
+                    y = L.mlp(lp["mlp"], h2, cfg)
+                x = x + y
         new_caches.append(nc)
     return x, aux, (tuple(new_caches) if bcaches is not None else None)
 
@@ -168,8 +170,13 @@ def forward(
     ``last_token_only`` computes logits for the final position only — the
     serving prefill path, which avoids materialising the (B, S, V) tensor.
     ``return_hidden`` skips the LM head and returns the final hidden states
-    (the chunked-CE training path computes logits per sequence chunk)."""
-    x = _embed_inputs(params, batch, cfg)
+    (the chunked-CE training path computes logits per sequence chunk).
+
+    Named scopes (``model.embed``, ``model.blocks``, ``model.head_loss``,
+    and ``model.mixer`` / ``model.mlp`` per layer) label the compiled
+    operations for the profiler; they are metadata only."""
+    with jax.named_scope("model.embed"):
+        x = _embed_inputs(params, batch, cfg)
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
@@ -193,23 +200,25 @@ def forward(
         return (x, aux + a), ncaches
 
     xs = params["blocks"] if caches is None else (params["blocks"], caches)
-    (x, aux), new_caches = jax.lax.scan(
-        scan_fn,
-        (x, jnp.zeros((), jnp.float32)),
-        xs,
-        unroll=cfg.n_blocks if getattr(cfg, "scan_unroll", False) else 1,
-    )
+    with jax.named_scope("model.blocks"):
+        (x, aux), new_caches = jax.lax.scan(
+            scan_fn,
+            (x, jnp.zeros((), jnp.float32)),
+            xs,
+            unroll=cfg.n_blocks if getattr(cfg, "scan_unroll", False) else 1,
+        )
 
-    if last_token_only:
-        x = x[:, -1:]
-    x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
-    if return_hidden:
-        return x, aux, new_caches
-    head = (
-        params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    ).astype(cfg.compute_dtype)
-    logits = (x @ head).astype(jnp.float32)
-    logits = shard(logits, "batch", None, "model")
+    with jax.named_scope("model.head_loss"):
+        if last_token_only:
+            x = x[:, -1:]
+        x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
+        if return_hidden:
+            return x, aux, new_caches
+        head = (
+            params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        ).astype(cfg.compute_dtype)
+        logits = (x @ head).astype(jnp.float32)
+        logits = shard(logits, "batch", None, "model")
     return logits, aux, new_caches
 
 
@@ -231,40 +240,41 @@ def train_loss(params, batch, cfg, *, window: Optional[int] = None):
     SPMD partitioner under manual subgroups); the (B, cs, V) intermediates
     are constrained to keep the vocab dim sharded."""
     x, aux, _ = forward(params, batch, cfg, window=window, return_hidden=True)
-    if "labels" in batch:
-        labels = batch["labels"]
-    else:
-        labels = batch["tokens"][:, 1:]
-        x = x[:, :-1]
-    if cfg.frontend != "none" and "tokens" in batch and x.shape[1] != labels.shape[1]:
-        x = x[:, -labels.shape[1]:]                      # drop frontend positions
-    head = (
-        params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    ).astype(cfg.compute_dtype)
-
-    def ce_chunk(args):
-        xc, lc = args                                    # (B, cs, D), (B, cs)
-        logits = shard((xc @ head).astype(jnp.float32), "batch", None, "model")
-        logz = jax.scipy.special.logsumexp(logits, axis=-1)
-        vocab_iota = jnp.arange(logits.shape[-1], dtype=lc.dtype)
-        mask = shard(lc[..., None] == vocab_iota, "batch", None, "model")
-        picked = jnp.sum(jnp.where(mask, logits, 0.0), axis=-1)
-        return jnp.sum(logz - picked)
-
-    b, s, d = x.shape
-    cs = CE_SEQ_CHUNK
-    if s > cs and s % cs == 0:
-        nc = s // cs
-        xb = jnp.moveaxis(x.reshape(b, nc, cs, d), 1, 0)
-        lb = jnp.moveaxis(labels.reshape(b, nc, cs), 1, 0)
-        fn = jax.checkpoint(ce_chunk)
-        if getattr(cfg, "scan_unroll", False):
-            total = sum(fn((xb[i], lb[i])) for i in range(nc))
+    with jax.named_scope("model.head_loss"):
+        if "labels" in batch:
+            labels = batch["labels"]
         else:
-            total = jnp.sum(jax.lax.map(fn, (xb, lb)))
-    else:
-        total = ce_chunk((x, labels))
-    return total / labels.size + aux
+            labels = batch["tokens"][:, 1:]
+            x = x[:, :-1]
+        if cfg.frontend != "none" and "tokens" in batch and x.shape[1] != labels.shape[1]:
+            x = x[:, -labels.shape[1]:]                      # drop frontend positions
+        head = (
+            params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        ).astype(cfg.compute_dtype)
+
+        def ce_chunk(args):
+            xc, lc = args                                    # (B, cs, D), (B, cs)
+            logits = shard((xc @ head).astype(jnp.float32), "batch", None, "model")
+            logz = jax.scipy.special.logsumexp(logits, axis=-1)
+            vocab_iota = jnp.arange(logits.shape[-1], dtype=lc.dtype)
+            mask = shard(lc[..., None] == vocab_iota, "batch", None, "model")
+            picked = jnp.sum(jnp.where(mask, logits, 0.0), axis=-1)
+            return jnp.sum(logz - picked)
+
+        b, s, d = x.shape
+        cs = CE_SEQ_CHUNK
+        if s > cs and s % cs == 0:
+            nc = s // cs
+            xb = jnp.moveaxis(x.reshape(b, nc, cs, d), 1, 0)
+            lb = jnp.moveaxis(labels.reshape(b, nc, cs), 1, 0)
+            fn = jax.checkpoint(ce_chunk)
+            if getattr(cfg, "scan_unroll", False):
+                total = sum(fn((xb[i], lb[i])) for i in range(nc))
+            else:
+                total = jnp.sum(jax.lax.map(fn, (xb, lb)))
+        else:
+            total = ce_chunk((x, labels))
+        return total / labels.size + aux
 
 
 def decode_step(params, tokens, caches, cfg, *, window: Optional[int] = None):

@@ -7,7 +7,9 @@ These tests compile each kernel the TPU backend routes through
 attached, at the widths ``mamba2-130m`` trains with: its whole bucketed
 parameter buffer, ternary blocks of 1024 lanes, and 4 stacked worker rows, so
 every decode grid has many tiles and several workers.  Only shapes are
-passed; nothing runs.
+passed; nothing runs.  Each compiled kernel keeps its own name, the one a
+profiler trace shows; the jaxpr of every kernel, the interpret-only ones
+too, carries that name.
 
 The topology is described inside a module fixture (never at import): only
 one process at a time may load the TPU compiler library, and every test
@@ -18,17 +20,22 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.dense import dense_copy, dense_decode_sum, dense_decode_sum_mean
 from repro.kernels.nat_pack import (
     LANES, nat_decode_sum, nat_decode_sum_apply, nat_decode_sum_mean, nat_pack,
     nat_pack_prng,
 )
 from repro.kernels.quantize_pack import quantize_pack, quantize_pack_prng
+from repro.kernels.sparse import (
+    sparse_decode_sum, sparse_decode_sum_mean, sparse_gather,
+)
 from repro.kernels.unpack_reduce import (
     unpack_reduce, unpack_reduce_apply, unpack_reduce_mean,
 )
@@ -121,8 +128,16 @@ KERNELS = [*_ternary(BLOCK), *_natural(BLOCK)]
 
 @pytest.mark.parametrize("name", KERNELS)
 def test_kernel_compiles_for_v5e(name, one_chip, flat_size):
+    """Each kernel compiles, and its Mosaic call carries its own stable name:
+    the instruction the profiler's trace shows is ``%<name>.<n>``, and the
+    name sits in the call's op_name, where only an explicit ``name=`` on the
+    ``pallas_call`` puts it."""
     fn, shapes = {**_ternary(flat_size), **_natural(flat_size)}[name]
-    assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
+    text = _compiled_text(fn, one_chip, *shapes)
+    calls = [l for l in text.splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == 1
+    assert re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", calls[0]), calls[0][:120]
+    assert f"/{name}/pallas_call" in calls[0]
 
 
 def _pallas_eqns(jaxpr):
@@ -164,3 +179,34 @@ def test_decode_grid_reduces_over_workers_last(name):
     assert eqn.params["grid_mapping"].grid == (2, 3)     # (m_tiles, workers)
     params = eqn.params["compiler_params"]["mosaic_tpu"]
     assert params.dimension_semantics == ("parallel", "arbitrary")
+
+
+_IDX = _S((3, 16), jnp.int32)
+_NAMED = {
+    **DECODES,
+    "quantize_pack": (quantize_pack, (_S((16, 128), jnp.float32),
+                                      _S((16, 128), jnp.uint32))),
+    "nat_pack": (nat_pack, (_S((16 * LANES,), jnp.float32),
+                            _S((16 * LANES,), jnp.uint32))),
+    "dense_copy": (dense_copy, (_S((256,), jnp.float32),)),
+    "dense_decode_sum": (dense_decode_sum, (_S((3, 256), jnp.float32),)),
+    "dense_decode_sum_mean": (dense_decode_sum_mean, (_S((3, 256), jnp.float32),)),
+    "sparse_gather": (sparse_gather, (_S((256,), jnp.float32), _S((16,), jnp.int32))),
+    "sparse_decode_sum": (
+        functools.partial(sparse_decode_sum, d=256),
+        (_IDX, _S((3, 16), jnp.float32), _S((16,), jnp.float32))),
+    "sparse_decode_sum_mean": (
+        functools.partial(sparse_decode_sum_mean, d=256),
+        (_IDX, _S((3, 16), jnp.float32), _S((16,), jnp.float32))),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_NAMED))
+def test_pallas_call_has_its_own_name(name):
+    """Every ``pallas_call`` in ``repro.kernels`` names itself after its
+    wrapper (the in-kernel-PRNG encodes, which only lower for the chip, are
+    checked in their TPU lowering above), so no two kernels share a name in
+    a trace.  This covers the interpret-only dense and sparse kernels too."""
+    kernel, args = _NAMED[name]
+    (eqn,) = _pallas_eqns(jax.make_jaxpr(kernel)(*args).jaxpr)
+    assert eqn.params["name"] == name
