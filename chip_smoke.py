@@ -14,7 +14,14 @@ One chip, in order:
    4 stacked worker rows: the encodes that take pre-drawn bits and every
    decode must match bit for bit; the encodes that draw their bits in the
    kernel must be unbiased;
-3. the trainer CLI (``repro.launch.train.main``) on the whole mamba2-130m,
+3. the Mamba-2 SSD chunk-scan kernel pair (``repro.kernels.ssd``) at
+   mamba2-130m's widths (batch 2, seq 4096), forward and vector-Jacobian
+   product, against its oracle run at float32 HIGHEST precision: each
+   output's error relative to the oracle's largest value must stay within
+   the bf16-operand bound its CPU test states, and the oracle at the
+   default precision (the XLA path the model takes without the kernel) is
+   printed beside it;
+4. the trainer CLI (``repro.launch.train.main``) on the whole mamba2-130m,
    at its published widths, for 5 steps: once with its default ``diana``
    operator and once with ``--compression natural``.  The compiled step must
    hold a Pallas kernel (``tpu_custom_call``), and the loss and the served
@@ -240,6 +247,65 @@ def natural_kernels(checks):
               var_sum(x), PRNG_DRAWS)
 
 
+# bf16 unit roundoff u = 2^-9; tests/test_ssd_kernel.py states the bounds:
+# error RMS <= 8u and largest error <= 16u, each relative to the oracle's
+SSD_RMS_TOL, SSD_MAX_TOL = 8 * 2.0 ** -9, 16 * 2.0 ** -9
+
+
+def ssd_kernels(checks):
+    from repro.configs import get_config
+    from repro.kernels import ref
+    from repro.kernels.ops import ssd_chunk_scan_op
+
+    cfg = get_config(ARCH)
+    sc = cfg.ssm
+    h, p, n, g = sc.n_heads(cfg.d_model), sc.head_dim, sc.d_state, sc.n_groups
+    b, l, q = 2, 4096, sc.chunk_size
+    print(f"ssd: batch {b}, seq {l}, {h} heads of {p}, d_state {n}, "
+          f"{g} group(s), chunk {q}", flush=True)
+    ks = jax.random.split(jax.random.PRNGKey(SEED + 3), 6)
+
+    @jax.jit
+    def inputs(ks):
+        x = jax.random.normal(ks[0], (b, l, h * p)).astype(jnp.bfloat16)
+        dt = jax.nn.softplus(jax.random.normal(ks[1], (b, l, h)) - 3.0)
+        a = -jnp.linspace(1.0, 16.0, h)
+        bm = jax.random.normal(ks[2], (b, l, g * n)).astype(jnp.bfloat16)
+        cm = jax.random.normal(ks[3], (b, l, g * n)).astype(jnp.bfloat16)
+        dy = jax.random.normal(ks[4], (b, l, h * p))
+        return (x, dt, a, bm, cm), dy
+
+    args, dy = inputs(ks)
+
+    def vjp(fn, precision):
+        @jax.jit
+        def run(args, dy):
+            with jax.default_matmul_precision(precision):
+                y, back = jax.vjp(fn, *args)
+                return (y, *back(dy))
+        return run(args, dy)
+
+    kern = vjp(lambda *a: ssd_chunk_scan_op(*a, chunk=q, n_groups=g), "default")
+    oracle = lambda *a: ref.ref_ssd_chunk_scan(*a, chunk=q, n_groups=g)
+    want = vjp(oracle, "highest")
+    xla = vjp(oracle, "default")
+
+    @jax.jit
+    def errors(got, want):
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        e = got - want
+        return (jnp.sqrt(jnp.mean(e * e) / jnp.mean(want * want)),
+                jnp.max(jnp.abs(e)) / jnp.max(jnp.abs(want)))
+
+    for name, k, w, x in zip(("y", "dx", "ddt", "dA", "dB", "dC"), kern, want, xla):
+        rms, mx = (float(v) for v in errors(k, w))
+        xrms, xmx = (float(v) for v in errors(x, w))
+        checks.record(f"ssd_chunk_scan {name}",
+                      rms <= SSD_RMS_TOL and mx <= SSD_MAX_TOL,
+                      f"kernel rms {rms} max {mx}; XLA default rms {xrms} "
+                      f"max {xmx}")
+
+
 # ---------------------------------------------------------------------------
 # The trainer
 # ---------------------------------------------------------------------------
@@ -377,6 +443,7 @@ def main(argv=None):
     if args.chips == 1:
         checks.phase("ternary kernels", ternary_kernels)
         checks.phase("natural kernels", natural_kernels)
+        checks.phase("ssd kernels", ssd_kernels)
         checks.phase("trainer diana", trainer, "diana",
                      _trainer_argv("--batch", "8"))
         checks.phase("trainer natural", trainer, "natural",

@@ -38,6 +38,7 @@ from .nat_pack import (
 )
 from .quantize_pack import quantize_pack, quantize_pack_prng
 from .sparse import sparse_decode_sum, sparse_decode_sum_mean, sparse_gather
+from .ssd import ssd_chunk_scan
 from .unpack_reduce import unpack_reduce, unpack_reduce_apply, unpack_reduce_mean
 
 __all__ = [
@@ -58,6 +59,7 @@ __all__ = [
     "dense_copy_op",
     "dense_decode_sum_op",
     "dense_decode_sum_mean_op",
+    "ssd_chunk_scan_op",
 ]
 
 
@@ -157,3 +159,9 @@ def dense_decode_sum_op(values):
 
 def dense_decode_sum_mean_op(values):
     return dense_decode_sum_mean(values, interpret=default_interpret())
+
+
+# -- Mamba-2 chunked SSD scan (differentiable) --------------------------------
+
+def ssd_chunk_scan_op(x, dt, a, bm, cm, *, chunk: int, n_groups: int):
+    return ssd_chunk_scan(x, dt, a, bm, cm, chunk, n_groups, default_interpret())

@@ -36,6 +36,7 @@ __all__ = [
     "ref_sparse_decode_sum",
     "ref_dense_decode_sum",
     "ref_apply_server",
+    "ref_ssd_chunk_scan",
 ]
 
 NAT_BIAS = 160  # == repro.core.compressors.natural._BIAS (int16 code bias)
@@ -146,3 +147,23 @@ def ref_dense_decode_sum(values: jax.Array) -> jax.Array:
     for i in range(1, values.shape[0]):
         acc = acc + values[i]
     return acc
+
+
+def ref_ssd_chunk_scan(x, dt, a, bm, cm, *, chunk: int, n_groups: int):
+    """Oracle of :func:`repro.kernels.ssd.ssd_chunk_scan`: the model's XLA
+    path, :func:`repro.models.mamba2._ssd_chunked`, fed as ``mamba_layer``
+    feeds it (x dt, A dt, and B, C repeated from groups to heads).
+
+    x (B, L, H*P) bf16, dt (B, L, H) f32, a (H,) f32, bm/cm (B, L, G*N) bf16
+    -> y (B, L, H*P) f32."""
+    from repro.models.mamba2 import _ssd_chunked
+
+    bsz, l, h = dt.shape
+    rep = h // n_groups
+
+    def heads(t):
+        return jnp.repeat(t.reshape(bsz, l, n_groups, -1), rep, axis=2)
+
+    xt = x.reshape(bsz, l, h, -1).astype(jnp.float32) * dt[..., None]
+    y = _ssd_chunked(xt, a * dt, heads(bm), heads(cm), chunk)
+    return y.reshape(bsz, l, -1)

@@ -7,6 +7,17 @@ block-decomposition.  Decode keeps the O(1) recurrent state
 ``(B, H, P, N)`` plus a depthwise-conv ring of width-1 inputs.
 
 Sequence length must divide ``chunk_size`` (all assigned shapes do).
+
+On a TPU the training/prefill scan takes the fused Pallas kernel pair
+:func:`repro.kernels.ssd.ssd_chunk_scan` (forward and backward; state and
+per-chunk intermediates stay in VMEM, ``C Bᵀ`` once per group, B and C never
+repeated to heads) where :func:`_fused_ssd` holds: the backend is a TPU,
+every mesh axis of size > 1 is manual (each device holds whole arrays), and
+the shapes tile — head dim 64 or 128, ``d_state`` a multiple of 128, chunk
+128 or 256 dividing the sequence, and each group a whole number of 128-lane
+head blocks (``mamba2-130m``: P=64, N=128, G=1, Q=256).  Everything else —
+the CPU, ``jamba``'s ``d_state=16`` layers, and the single-token recurrent
+decode (``cache`` given) — runs the XLA :func:`_ssd_chunked` below, unchanged.
 """
 
 from __future__ import annotations
@@ -17,7 +28,10 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .sharding import shard
+from repro.kernels.ops import ssd_chunk_scan_op
+from repro.kernels.ssd import supports as ssd_kernel_supports
+
+from .sharding import arrays_are_local, shard
 
 __all__ = ["init_mamba", "mamba_layer", "MambaCache", "init_mamba_cache"]
 
@@ -123,6 +137,14 @@ def _ssd_chunked(xt, at, b_, c_, chunk: int, unroll: bool = False):
     return (y_diag + y_off).reshape(bsz, l, h, p)
 
 
+def _fused_ssd(cfg, s: int, chunk: int) -> bool:
+    """Whether ``mamba_layer`` takes the fused SSD kernel (module docstring)."""
+    sc, d_in, h, p, n, g = _dims(cfg)
+    return (jax.default_backend() == "tpu" and arrays_are_local()
+            and ssd_kernel_supports(seq=s, chunk=chunk, n_heads=h, head_dim=p,
+                                    d_state=n, n_groups=g))
+
+
 def _split_proj(proj, cfg):
     sc, d_in, h, p, n, g = _dims(cfg)
     z, x, b_, c_, dt = jnp.split(
@@ -165,38 +187,44 @@ def mamba_layer(
         new_conv = hist[:, 1:]
     xr, braw, craw = jnp.split(conv_out, [d_in, d_in + g * n], axis=-1)
 
-    xt = xr.reshape(bsz, s, h, p)
-    xt = shard(xt, "batch", None, "model", None)
-    bmat = braw.reshape(bsz, s, g, n)
-    cmat = craw.reshape(bsz, s, g, n)
-    bh = jnp.repeat(bmat, rep, axis=2)                           # (B,S,H,N)
-    ch = jnp.repeat(cmat, rep, axis=2)
-
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"])     # (B,S,H)
     a = -jnp.exp(params["A_log"])                                # (H,)
+    chunk = min(sc.chunk_size, s)
 
-    if cache is None:
-        y = _ssd_chunked(
-            xt.astype(jnp.float32) * dt[..., None],
-            a * dt,
-            bh,
-            ch,
-            min(sc.chunk_size, s),
-            unroll=getattr(cfg, "scan_unroll", False),
-        )
+    if cache is None and _fused_ssd(cfg, s, chunk):
+        # Heads stay side by side in (B, S, H*P), as the kernel reads and
+        # writes them: no relayout to (B, S, H, P) and back.
+        y = ssd_chunk_scan_op(xr, dt, a, braw, craw, chunk=chunk, n_groups=g)
+        y = y + jnp.repeat(params["D"], p) * xr.astype(jnp.float32)
     else:
-        dt0 = dt[:, 0]                                           # (B,H)
-        decay = jnp.exp(a * dt0)                                 # (B,H)
-        xin = xt[:, 0].astype(jnp.float32) * dt0[..., None]      # (B,H,P)
-        new_ssm = (
-            cache.ssm * decay[:, :, None, None]
-            + xin[..., None] * bh[:, 0, :, None, :].astype(jnp.float32)
-        )
-        y = jnp.einsum("bhpn,bhn->bhp", new_ssm, ch[:, 0].astype(jnp.float32))[:, None]
-        new_cache = MambaCache(conv=new_conv, ssm=new_ssm, pos=cache.pos + 1)
+        xt = xr.reshape(bsz, s, h, p)
+        xt = shard(xt, "batch", None, "model", None)
+        bmat = braw.reshape(bsz, s, g, n)
+        cmat = craw.reshape(bsz, s, g, n)
+        bh = jnp.repeat(bmat, rep, axis=2)                       # (B,S,H,N)
+        ch = jnp.repeat(cmat, rep, axis=2)
+        if cache is None:
+            y = _ssd_chunked(
+                xt.astype(jnp.float32) * dt[..., None],
+                a * dt,
+                bh,
+                ch,
+                chunk,
+                unroll=getattr(cfg, "scan_unroll", False),
+            )
+        else:
+            dt0 = dt[:, 0]                                       # (B,H)
+            decay = jnp.exp(a * dt0)                             # (B,H)
+            xin = xt[:, 0].astype(jnp.float32) * dt0[..., None]  # (B,H,P)
+            new_ssm = (
+                cache.ssm * decay[:, :, None, None]
+                + xin[..., None] * bh[:, 0, :, None, :].astype(jnp.float32)
+            )
+            y = jnp.einsum("bhpn,bhn->bhp", new_ssm, ch[:, 0].astype(jnp.float32))[:, None]
+            new_cache = MambaCache(conv=new_conv, ssm=new_ssm, pos=cache.pos + 1)
 
-    y = y + params["D"][:, None] * xt.astype(jnp.float32)
-    y = y.reshape(bsz, s, d_in)
+        y = y + params["D"][:, None] * xt.astype(jnp.float32)
+        y = y.reshape(bsz, s, d_in)
 
     # gated RMSNorm (mamba2): norm(y * silu(z))
     gated = y * jax.nn.silu(z.astype(jnp.float32))

@@ -22,7 +22,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-__all__ = ["shard", "shard_spec", "sharding_policy", "GSPMDPolicy", "current_policy", "LOGICAL_RULES"]
+__all__ = ["shard", "shard_spec", "arrays_are_local", "sharding_policy", "GSPMDPolicy", "current_policy", "LOGICAL_RULES"]
 
 # Logical axis -> mesh axes. 'batch' spans the data axes — including the
 # optional 'node' axis of the hierarchical aggregation topology (a worker
@@ -143,6 +143,16 @@ def shard_replicated(x):
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, P(*((None,) * x.ndim)))
     )
+
+
+def arrays_are_local() -> bool:
+    """True where model code holds each array whole on its device: no policy,
+    or every mesh axis of size > 1 is manual.  A Pallas call cannot be
+    partitioned automatically, so model code takes a kernel only here."""
+    policy = current_policy()
+    if not isinstance(policy, GSPMDPolicy):
+        return True
+    return all(n == 1 or a in policy.manual for a, n in policy.mesh.shape.items())
 
 
 def shard_spec(*logical) -> Optional[P]:

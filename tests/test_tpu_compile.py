@@ -210,3 +210,32 @@ def test_pallas_call_has_its_own_name(name):
     kernel, args = _NAMED[name]
     (eqn,) = _pallas_eqns(jax.make_jaxpr(kernel)(*args).jaxpr)
     assert eqn.params["name"] == name
+
+
+def test_mamba_layer_compiles_with_ssd_kernels(one_chip, monkeypatch):
+    """One whole mamba2-130m layer under ``jax.checkpoint``, forward and
+    backward, at batch 1 and seq 4096, as the model routes it on a TPU: the
+    compiled program holds the two SSD kernels and none of the XLA scan's
+    per-head (Q, Q) f32 buffers or head-repeated (…, 24, 128) B/C buffers."""
+    import repro.models.mamba2 as M
+    from repro.configs import get_config
+
+    cfg = get_config("mamba2-130m")
+    params = jax.eval_shape(lambda k: M.init_mamba(k, cfg, jnp.bfloat16),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), params)
+    x = jax.ShapeDtypeStruct((1, 4096, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+    layer = jax.checkpoint(lambda p, x: M.mamba_layer(p, x, cfg)[0])
+
+    def loss(p, x):
+        return jnp.sum(layer(p, x).astype(jnp.float32))
+
+    with monkeypatch.context() as m:   # the model asks the backend; trace as on a TPU
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        traced = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(params, x)
+    text = traced.lower().compile().as_text()
+    calls = re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    assert sorted(set(calls)) == ["ssd_chunk_scan", "ssd_chunk_scan_bwd"], calls
+    assert not re.search(r"f32\[[\d,]*256,256\]", text)
+    assert not re.search(r"\[[\d,]*24,128\]", text)
