@@ -8,10 +8,16 @@ configuration and traffic mix, and the files are found from those names.
 * ``bench/limits/<workload>.json``: the numbers that decide ``correct`` and
   the limit of each, with the readings each limit was set from.
 * ``bench/metrics/<metric>.py``: one reader per per-layer metric.
+* ``bench/layers/<kind>.py``: the reference of one layer kind of a
+  configuration's ``pattern`` (``bench/reference.py``).
+* ``bench/readings/<name>.py``: a number the comparison reads beside its
+  built-in ones (``bench/compare.py``).
 """
 
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 import os
 import re
@@ -75,12 +81,27 @@ def resolve(workload: str, root: str = ROOT) -> Dict[str, Any]:
                 per_layer=per_layer, run_seconds=bench["run_seconds"])
 
 
-def load_metric_reader(metric: str):
-    """The ``read(ctx)`` function of ``bench/metrics/<metric>.py``."""
-    import importlib.util
+def module_path(directory: str, name: str, what: str) -> str:
+    """``<directory>/<name>.py``; a name with no file is an error that names
+    the file to add."""
+    if not NAME_RE.match(name):
+        raise ValueError(f"bad {what} name {name!r}")
+    path = os.path.join(directory, name + ".py")
+    if not os.path.exists(path):
+        raise ValueError(f"no {what} {name!r}: add {os.path.relpath(path, ROOT)}")
+    return path
 
+
+@functools.lru_cache(maxsize=None)
+def load_module(path: str):
+    """The module of one file found by name, loaded once per process."""
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + re.sub(r"\W", "_", metric), metric_path(metric))
+        "bench_file_" + re.sub(r"\W", "_", os.path.relpath(path, BENCH)), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_metric_reader(metric: str):
+    """The ``read(ctx)`` function of ``bench/metrics/<metric>.py``."""
+    return load_module(metric_path(metric)).read

@@ -5,9 +5,12 @@ under test: it imports nothing from ``src/`` and takes nothing the program
 made.  The weights it starts from come from :func:`make_params`, the
 benchmark's own generator, regenerated from the seed.
 
-* Mamba-2 (arXiv:2405.21060): in-projection, causal depthwise convolution,
-  the SSD scan by the paper's minimal chunked listing, gated RMSNorm,
-  out-projection.
+* The model: embedding, a scan over blocks that each apply the
+  configuration's ``pattern`` (``x + mixer(norm1(x))``, then, where ``mlp``
+  is not ``"none"``, ``x + mlp(norm2(x))``), final RMSNorm, the tied or
+  untied head and a chunked cross-entropy.  Each layer kind of the pattern
+  is a module ``bench/layers/<kind>.py`` found by its name (see
+  :func:`layer_kind`).
 * DIANA (arXiv:1901.09269, Algorithm 1) with block ternary p=inf
   quantization (Def. 2) per leaf, the memory rate alpha = alpha_inf(B) / 2
   (Lemma 1, Corollary 1), and heavy-ball momentum on the served direction.
@@ -24,17 +27,20 @@ comparison has to refuse.
 from __future__ import annotations
 
 import math
+import os
 from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import cells
+
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
 VOCAB_PAD = 4096          # the embedding table is padded to a multiple of this
 CE_CHUNK = 512            # positions per chunk of the loss
-SSD_CHUNK = 128           # the reference's own SSD chunk (any size is exact)
+LAYERS = os.path.join(cells.BENCH, "layers")
 
 ROUNDING = {"f32": None, "bf16": jnp.bfloat16, "fp8": jnp.float8_e4m3fn}
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
@@ -100,7 +106,7 @@ def seed_key(seed: int):
 class Leaf(NamedTuple):
     shape: tuple
     dtype: str
-    init: str          # normal | ones | zeros | a_log | dt_bias
+    init: str          # normal | ones | zeros | a name in a layer kind's INITS
     scale: float = 1.0
 
 
@@ -108,21 +114,39 @@ def padded_vocab(model) -> int:
     return -(-model["vocab"] // VOCAB_PAD) * VOCAB_PAD
 
 
-def _dims(model):
-    d = model["d_model"]
-    ssm = model["ssm"]
-    d_in = ssm["expand"] * d
-    return dict(d=d, d_in=d_in, h=d_in // ssm["head_dim"], p=ssm["head_dim"],
-                n=ssm["d_state"], g=ssm["n_groups"], w=ssm["conv_width"])
+def layer_kind(kind: str, layers: str = LAYERS):
+    """The module ``<layers>/<kind>.py`` of one layer kind.  It gives
+    ``layout(model, stacked)``, the kind's leaves as the program lays them
+    out under ``mixer`` or ``mlp``, and ``apply(p, x, model, dot) -> (y,
+    aux)``, the layer on its normed input in float32 with every product
+    through ``dot``; ``aux`` is a term the loss adds, ``0`` where there is
+    none.  Optionally ``INITS``, its own initialisers by name, and
+    ``WHOLE_BATCH = True`` where it computes over all of a worker's rows at
+    once."""
+    return cells.load_module(cells.module_path(layers, kind, "reference for layer kind"))
 
 
-def param_layout(model) -> dict:
+def pattern_kinds(model, layers: str = LAYERS) -> list:
+    """(mixer module, mlp module or None) for each layer of the pattern."""
+    return [(layer_kind(spec["mixer"], layers),
+             None if spec["mlp"] == "none" else layer_kind(spec["mlp"], layers))
+            for spec in model["pattern"]]
+
+
+def _modules(model, layers: str) -> list:
+    """The pattern's layer kinds, each once, in the pattern's order."""
+    out = []
+    for pair in pattern_kinds(model, layers):
+        out += [m for m in pair if m is not None and m not in out]
+    return out
+
+
+def param_layout(model, layers: str = LAYERS) -> dict:
     """The parameter tree as the program lays it out: names, shapes, types
     and the generator's initialisation of each leaf."""
     pdt = model["param_dtype"]
     L = model["n_layers"] // len(model["pattern"])
     d = model["d_model"]
-    out_scale = 1.0 / math.sqrt(2 * model["n_layers"])
     tree = {"embed": Leaf((padded_vocab(model), d), pdt, "normal", 0.02),
             "final_norm": {"scale": Leaf((d,), pdt, "ones")}}
     if not model["tie_embeddings"]:
@@ -132,26 +156,13 @@ def param_layout(model) -> dict:
         return Leaf((L,) + tuple(shape), dtype, init, scale)
 
     block = {}
-    for i, spec in enumerate(model["pattern"]):
-        if (spec["mixer"], spec["mlp"]) != ("mamba", "none"):
-            raise ValueError(f"the reference has Mamba-2 layers only, not {spec}")
-        m = _dims(model)
-        conv_ch = m["d_in"] + 2 * m["g"] * m["n"]
-        d_proj = 2 * m["d_in"] + 2 * m["g"] * m["n"] + m["h"]
-        block[f"layer{i}"] = {
-            "norm1": {"scale": stacked((d,), pdt, "ones")},
-            "mixer": {
-                "in_proj": stacked((d, d_proj), pdt, "normal", 1 / math.sqrt(d)),
-                "conv_w": stacked((m["w"], conv_ch), pdt, "normal", 1 / math.sqrt(m["w"])),
-                "conv_b": stacked((conv_ch,), pdt, "zeros"),
-                "dt_bias": stacked((m["h"],), "float32", "dt_bias"),
-                "A_log": stacked((m["h"],), "float32", "a_log"),
-                "D": stacked((m["h"],), "float32", "ones"),
-                "norm_scale": stacked((m["d_in"],), pdt, "ones"),
-                "out_proj": stacked((m["d_in"], d), pdt, "normal",
-                                    out_scale / math.sqrt(m["d_in"])),
-            },
-        }
+    for i, (mixer, mlp) in enumerate(pattern_kinds(model, layers)):
+        lp = {"norm1": {"scale": stacked((d,), pdt, "ones")},
+              "mixer": mixer.layout(model, stacked)}
+        if mlp is not None:
+            lp["norm2"] = {"scale": stacked((d,), pdt, "ones")}
+            lp["mlp"] = mlp.layout(model, stacked)
+        block[f"layer{i}"] = lp
     tree["blocks"] = block
     return tree
 
@@ -166,7 +177,7 @@ def leaf_paths(tree) -> List[str]:
     return ["/".join(str(getattr(k, "key", k)) for k in path) for path, _ in flat]
 
 
-def _init_leaf(spec: Leaf, key):
+def _init_leaf(spec: Leaf, key, inits):
     dt = DTYPES[spec.dtype]
     if spec.init == "normal":
         x = jax.random.normal(key, spec.shape, F32) * spec.scale
@@ -174,25 +185,26 @@ def _init_leaf(spec: Leaf, key):
         x = jnp.ones(spec.shape, F32)
     elif spec.init == "zeros":
         x = jnp.zeros(spec.shape, F32)
-    elif spec.init == "a_log":            # A uniform in [1, 16] (Mamba-2)
-        x = jnp.log(jax.random.uniform(key, spec.shape, F32, 1.0, 16.0))
-    elif spec.init == "dt_bias":          # softplus^-1 of dt, log-uniform [1e-3, 1e-1]
-        dt_ = jnp.exp(jax.random.uniform(key, spec.shape, F32,
-                                         math.log(1e-3), math.log(1e-1)))
-        x = dt_ + jnp.log(-jnp.expm1(-dt_))
+    elif spec.init in inits:
+        x = inits[spec.init](key, spec.shape)
     else:
         raise ValueError(spec.init)
     return x.astype(dt)
 
 
-def _builder(model):
-    layout = param_layout(model)
+def _builder(model, layers):
+    layout = param_layout(model, layers)
+    inits = {}
+    for mod in _modules(model, layers):
+        for name, fn in getattr(mod, "INITS", {}).items():
+            if inits.setdefault(name, fn) is not fn:
+                raise ValueError(f"two layer kinds define the initialiser {name!r}")
     leaves, treedef = jax.tree_util.tree_flatten(layout, is_leaf=_is_leaf)
 
     def build(key):
         keys = jax.random.split(key, len(leaves))
         return jax.tree_util.tree_unflatten(
-            treedef, [_init_leaf(s, k) for s, k in zip(leaves, keys)])
+            treedef, [_init_leaf(s, k, inits) for s, k in zip(leaves, keys)])
 
     return build
 
@@ -201,12 +213,12 @@ def _weights_key(seed: int):
     return jax.random.fold_in(seed_key(seed), 0x3E16)
 
 
-def make_params(model, seed: int):
+def make_params(model, seed: int, layers: str = LAYERS):
     """The benchmark's weights: every leaf from ``seed``, in one jitted call
     on the default device, in the type it is served in.  The program and the
     reference both take them from this one program: on a TPU another
     program that draws the same numbers can round them differently."""
-    return jax.jit(_builder(model))(_weights_key(seed))
+    return jax.jit(_builder(model, layers))(_weights_key(seed))
 
 
 def host_change_norms(new, old) -> Dict[str, float]:
@@ -227,97 +239,41 @@ def rms_norm(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 
 
-def segsum(a):
-    """(..., T) -> (..., T, T): entry [i, j] is a[j+1] + ... + a[i] for
-    j <= i, and -inf above the diagonal (the stable form of the listing)."""
-    t = a.shape[-1]
-    x = jnp.broadcast_to(a[..., :, None], a.shape + (t,))
-    x = jnp.where(jnp.tril(jnp.ones((t, t), bool), -1), x, 0.0)
-    cs = jnp.cumsum(x, axis=-2)
-    return jnp.where(jnp.tril(jnp.ones((t, t), bool)), cs, -jnp.inf)
+def _add_aux(total, aux):
+    """The sum of the layers' terms; a layer without one returns 0 and adds
+    nothing, so a pattern without any has no term at all."""
+    if isinstance(aux, (int, float)) and aux == 0:
+        return total
+    return aux if total is None else total + aux
 
 
-def ssd(x, a, b, c, chunk, dot):
-    """Mamba-2's SSD, the paper's minimal chunked listing.
-
-    x: (B, L, H, P) inputs already times dt; a: (B, L, H) log-decays (A dt);
-    b, c: (B, L, G, N), head h reads group h // (H / G).  Returns (B, L, H, P).
-    """
-    bsz, l, h, p = x.shape
-    g, n = b.shape[2], b.shape[3]
-    r = h // g
-    nc = l // chunk
-    x = x.reshape(bsz, nc, chunk, h, p)
-    b = b.reshape(bsz, nc, chunk, g, n)
-    c = c.reshape(bsz, nc, chunk, g, n)
-    a = jnp.moveaxis(a.reshape(bsz, nc, chunk, h), 3, 1)          # (B,H,C,Q)
-    a_cs = jnp.cumsum(a, axis=-1)
-
-    # within each chunk: (C B^T o L) X
-    lmat = jnp.exp(segsum(a))                                     # (B,H,C,Q,Q)
-    cb = dot("bclgn,bcsgn->bcgls", c, b)                          # (B,C,G,Q,Q)
-    cb = jnp.repeat(cb, r, axis=2) * jnp.moveaxis(lmat, 1, 2)     # (B,C,H,Q,Q)
-    y_diag = dot("bchls,bcshp->bclhp", cb, x)
-
-    # each chunk's contribution to the state at its end
-    decay_states = jnp.exp(a_cs[..., -1:] - a_cs)                 # (B,H,C,Q)
-    bh = jnp.repeat(b, r, axis=3) * jnp.moveaxis(decay_states, 1, 3)[..., None]
-    states = dot("bclhn,bclhp->bchpn", bh, x)                     # (B,C,H,P,N)
-
-    # the state entering each chunk
-    states = jnp.concatenate([jnp.zeros_like(states[:, :1]), states], axis=1)
-    a_tot = jnp.pad(a_cs[..., -1], ((0, 0), (0, 0), (1, 0)))      # (B,H,C+1)
-    decay_chunk = jnp.exp(segsum(a_tot))                          # (B,H,C+1,C+1)
-    states = jnp.einsum("bhzc,bchpn->bzhpn", decay_chunk, states,
-                        precision=HIGHEST)[:, :-1]
-
-    # what the entering state adds to each position
-    ch = jnp.repeat(c, r, axis=3) * jnp.moveaxis(jnp.exp(a_cs), 1, 3)[..., None]
-    y_off = dot("bclhn,bchpn->bclhp", ch, states)
-    return (y_diag + y_off).reshape(bsz, l, h, p)
-
-
-def mamba_mixer(p, x, model, dot):
-    m = _dims(model)
-    d_in, h, hp, n, g, w = m["d_in"], m["h"], m["p"], m["n"], m["g"], m["w"]
-    bsz, s, _ = x.shape
-    proj = dot("bsd,de->bse", x, p["in_proj"])
-    z, xr, braw, craw, dt = jnp.split(
-        proj, [d_in, 2 * d_in, 2 * d_in + g * n, 2 * d_in + 2 * g * n], axis=-1)
-    u = jnp.concatenate([xr, braw, craw], axis=-1)
-    up = jnp.pad(u, ((0, 0), (w - 1, 0), (0, 0)))
-    conv = sum(up[:, i:i + s] * p["conv_w"][i] for i in range(w)) + p["conv_b"]
-    u = jax.nn.silu(conv)
-    xr, braw, craw = jnp.split(u, [d_in, d_in + g * n], axis=-1)
-    xs = xr.reshape(bsz, s, h, hp)
-    dt = jax.nn.softplus(dt + p["dt_bias"])                       # (B,S,H)
-    a = -jnp.exp(p["A_log"])                                      # (H,)
-    y = ssd(xs * dt[..., None], a * dt, braw.reshape(bsz, s, g, n),
-            craw.reshape(bsz, s, g, n), min(SSD_CHUNK, s), dot)
-    y = (y + p["D"][:, None] * xs).reshape(bsz, s, d_in)
-    y = rms_norm(y * jax.nn.silu(z), p["norm_scale"], model["norm_eps"])
-    return dot("bse,ed->bsd", y, p["out_proj"])
-
-
-def _block(x, bp, model, dot):
-    for i in range(len(model["pattern"])):
+def _block(x, bp, kinds, model, dot):
+    aux = None
+    for i, (mixer, mlp) in enumerate(kinds):
         lp = bp[f"layer{i}"]
-        hx = rms_norm(x, lp["norm1"]["scale"], model["norm_eps"])
-        x = x + mamba_mixer(lp["mixer"], hx, model, dot)
-    return x
+        y, a = mixer.apply(lp["mixer"], rms_norm(x, lp["norm1"]["scale"], model["norm_eps"]),
+                           model, dot)
+        x, aux = x + y, _add_aux(aux, a)
+        if mlp is not None:
+            y, a = mlp.apply(lp["mlp"], rms_norm(x, lp["norm2"]["scale"], model["norm_eps"]),
+                             model, dot)
+            x, aux = x + y, _add_aux(aux, a)
+    return x, aux
 
 
-def loss_terms(params, tokens, labels, model, precision="f32"):
-    """The sum of next-token cross-entropies over every position.  The
+def loss_terms(params, tokens, labels, model, precision="f32", layers: str = LAYERS):
+    """The sum of next-token cross-entropies over every position, and the
+    sum of the layers' terms (``None`` where no layer has one).  The
     softmax runs over the padded vocabulary, as the program's head does.
     ``params`` may be of any float type; all math is float32."""
     dot = make_dot(precision)
+    kinds = pattern_kinds(model, layers)
     p = jax.tree_util.tree_map(lambda a: a.astype(F32), params)
 
     def body(x, bp):
-        return jax.checkpoint(lambda x_, bp_: _block(x_, bp_, model, dot))(x, bp), None
+        return jax.checkpoint(lambda x_, bp_: _block(x_, bp_, kinds, model, dot))(x, bp)
 
-    x, _ = jax.lax.scan(body, p["embed"][tokens], p["blocks"])
+    x, auxes = jax.lax.scan(body, p["embed"][tokens], p["blocks"])
     x = rms_norm(x, p["final_norm"]["scale"], model["norm_eps"])
     head = p["embed"] if model["tie_embeddings"] else p["lm_head"].T   # (Vp, D)
     bsz, s, d = x.shape
@@ -332,24 +288,30 @@ def loss_terms(params, tokens, labels, model, precision="f32"):
 
     xs = jnp.moveaxis(x.reshape(bsz, s // cs, cs, d), 1, 0)
     ls = jnp.moveaxis(labels.reshape(bsz, s // cs, cs), 1, 0)
-    return jnp.sum(jax.lax.map(ce, (xs, ls)))
+    return jnp.sum(jax.lax.map(ce, (xs, ls))), None if auxes is None else jnp.sum(auxes)
 
 
 ROW_BLOCK = 2             # rows whose gradients are taken together
 _LOSS_GRAD_CACHE: Dict = {}
 
 
-def loss_and_grad(params32, tokens, labels, model, precision="f32"):
-    """The mean loss over all positions and its float32 gradient, taken in
-    blocks of rows."""
+def loss_and_grad(params32, tokens, labels, model, precision="f32", layers: str = LAYERS):
+    """The mean loss over all positions, plus the layers' terms, and its
+    float32 gradient, taken in blocks of rows: all of the rows at once where
+    a layer kind of the pattern sets ``WHOLE_BATCH``.  Each block adds its
+    share of the rows times its terms."""
     rows = tokens.shape[0]
-    rb = math.gcd(rows, ROW_BLOCK)
+    whole = any(getattr(m, "WHOLE_BATCH", False) for m in _modules(model, layers))
+    rb = rows if whole else math.gcd(rows, ROW_BLOCK)
     n_pos = tokens.size
-    key = (id(model), precision, n_pos)
+    key = (id(model), precision, n_pos, rows, layers)
     fn = _LOSS_GRAD_CACHE.get(key)
     if fn is None:
         def part(p, t, l):
-            loss = loss_terms(p, t, l, model, precision) / n_pos
+            ce, aux = loss_terms(p, t, l, model, precision, layers)
+            loss = ce / n_pos
+            if aux is not None:
+                loss = loss + aux * (rb / rows)
             return loss, loss
         fn = jax.jit(jax.grad(part, has_aux=True))
         _LOSS_GRAD_CACHE[key] = fn

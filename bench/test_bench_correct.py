@@ -16,6 +16,7 @@ import jax
 import pytest
 
 from bench import compare, harness, tiny
+from bench.reference import Readings
 
 LIMITS = {"compared": {
     "loss_gap": {"limit": 5e-4},           # sound <= 2.4e-4, control >= 6.4e-4
@@ -100,3 +101,28 @@ def test_four_workers_sound_and_no_exchange_refused():
     assert out.returncode == 0, out.stderr[-3000:]
     got = json.loads(out.stdout.strip().splitlines()[-1])
     assert got == {"sound": True, "no_exchange": False}
+
+
+def test_a_compared_number_from_its_own_file():
+    """``bench/testdata/readings/first_loss_gap.py`` is read beside the
+    built-in numbers and decided on; a compared name with neither a
+    built-in nor a file is an error that names the file to add."""
+    where = os.path.join(os.path.dirname(os.path.abspath(__file__)), "testdata", "readings")
+    norms = {"a": 1.0, "b": 2.0}
+    ref = Readings([3.0, 2.5, 2.0], norms, norms, norms)
+    prog = Readings([3.25, 2.5, 2.0], norms, norms)
+    limits = {"compared": {"loss_gap": {"limit": 1.0}, "first_loss_gap": {"limit": 0.5}}}
+    values = compare.readings(prog, ref, where)
+    assert values["first_loss_gap"] == 0.25 and "first_loss_gap" not in compare.readings(prog, ref)
+    assert compare.decide(values, limits)[0]
+    builtin = compare.readings(prog, ref)
+    ok, checks = compare.decide(builtin, limits, prog, ref, where)
+    assert ok and checks["first_loss_gap"] == {"value": 0.25, "limit": 0.5}
+    limits["compared"]["first_loss_gap"]["limit"] = 0.2
+    assert not compare.decide(builtin, limits, prog, ref, where)[0]
+    assert not compare.decide(builtin, limits, Readings([], norms, norms), ref, where)[0]
+    limits["compared"]["absent_gap"] = {"limit": 1.0}
+    with pytest.raises(ValueError, match="bench/testdata/readings/absent_gap.py"):
+        compare.decide(builtin, limits, prog, ref, where)
+    with pytest.raises(ValueError, match="add bench/readings/absent_gap.py"):
+        compare.decide(builtin, {"compared": {"absent_gap": {"limit": 1.0}}}, prog, ref)

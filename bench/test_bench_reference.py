@@ -1,5 +1,8 @@
-"""The reference against the program at test size in float32, and its parts
-against their definitions."""
+"""The reference against the program at test size in float32, its parts
+against their definitions, and its lookup of layer kinds by file."""
+
+import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -8,6 +11,10 @@ import pytest
 
 from bench import feed, program, tiny
 from bench import reference as R
+
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "testdata")
+with open(os.path.join(DATA, "reference_tiny_mamba.json")) as _f:
+    GOLDEN = json.load(_f)
 
 
 @pytest.mark.parametrize("family", ["mamba"])
@@ -38,7 +45,7 @@ def test_ssd_equals_the_recurrence():
     a = -jnp.asarray(rng.uniform(0.01, 0.5, (b, l, h)), jnp.float32)
     bm = jnp.asarray(rng.standard_normal((b, l, g, n)), jnp.float32)
     cm = jnp.asarray(rng.standard_normal((b, l, g, n)), jnp.float32)
-    got = R.ssd(x, a, bm, cm, 16, R.make_dot("f32"))
+    got = R.layer_kind("mamba").ssd(x, a, bm, cm, 16, R.make_dot("f32"))
     bh, ch = jnp.repeat(bm, h // g, axis=2), jnp.repeat(cm, h // g, axis=2)
 
     def step(state, inp):
@@ -84,3 +91,82 @@ def test_weights_are_the_seeds():
         assert a.dtype == b.dtype and bool(jnp.all(a == b))
     assert any(not bool(jnp.all(a == c)) for a, c in zip(
         jax.tree_util.tree_leaves(one), jax.tree_util.tree_leaves(other)))
+
+
+@pytest.mark.parametrize("run", GOLDEN["runs"], ids=lambda r: f"w{r['workers']}-s{r['seed']}")
+def test_reference_gives_the_recorded_readings(run):
+    """Three steps of DIANA on the tiny Mamba model, 1 and 4 workers on one
+    CPU device, equal to the readings recorded before the layer kinds moved
+    to ``bench/layers/``: every loss and per-leaf norm, exactly."""
+    spec = tiny.spec("mamba", workers=run["workers"])
+    model, traffic = spec["config_doc"]["model"], spec["traffic"]
+    seed = run["seed"]
+    batches = [[(b["tokens"], b["labels"])
+                for b in feed.worker_batches(traffic, model["vocab"], seed, t)]
+               for t in range(3)]
+    got = R.diana_reference(model, traffic, R.make_params(model, seed), batches, seed,
+                            devices=[jax.devices()[0]] * run["workers"])
+    assert got._asdict() == run["readings"]
+
+
+def test_a_layer_kind_without_a_file_names_the_file():
+    model = dict(tiny.MAMBA, pattern=[{"mixer": "attn", "mlp": "moe"}])
+    with pytest.raises(ValueError, match="no reference for layer kind 'attn': "
+                                         "add bench/layers/attn.py"):
+        R.param_layout(model)
+    model["pattern"] = [{"mixer": "mamba", "mlp": "moe"}]
+    with pytest.raises(ValueError, match="bench/layers/moe.py"):
+        R.param_layout(model)
+
+
+def test_a_layer_kind_from_its_own_file():
+    """A toy kind in ``bench/testdata/layers/``, as mixer and mlp: its
+    leaves, its own initialiser, and its term in the loss, over the whole
+    batch at once (``WHOLE_BATCH``)."""
+    where = os.path.join(DATA, "layers")
+    model = dict(tiny.MAMBA, pattern=[{"mixer": "toy", "mlp": "toy"}], n_layers=2,
+                 param_dtype="float32")
+    layout = R.param_layout(model, where)
+    assert layout["blocks"]["layer0"]["mixer"]["w"] == R.Leaf((2, 64, 64), "float32",
+                                                               "toy_uniform")
+    assert set(layout["blocks"]["layer0"]) == {"norm1", "mixer", "norm2", "mlp"}
+    params = R.make_params(model, 2**31 + 3, where)
+    w = params["blocks"]["layer0"]["mlp"]["w"]
+    assert w.dtype == jnp.float32 and 0.09 < float(jnp.max(jnp.abs(w))) <= 0.1
+
+    b = feed.worker_batches(tiny.spec("mamba", batch=4)["traffic"], model["vocab"], 7, 0)[0]
+    tok, lab = jnp.asarray(b["tokens"]), jnp.asarray(b["labels"])
+    ce, aux = R.loss_terms(params, tok, lab, model, layers=where)
+    loss, grads = R.loss_and_grad(params, tok, lab, model, layers=where)
+    assert float(aux) > 0
+    # the term of all four rows at once; blocks of two rows would differ
+    assert float(loss) == pytest.approx(float(ce) / tok.size + float(aux), rel=1e-6)
+    halves = [R.loss_terms(params, tok[r:r + 2], lab[r:r + 2], model, layers=where)[1]
+              for r in (0, 2)]
+    assert abs(float(sum(halves)) / 2 - float(aux)) > 1e-3 * float(aux)
+    # the term has a gradient of its own: without it the gradient differs
+    grad_ce = jax.grad(lambda p: R.loss_terms(p, tok, lab, model, layers=where)[0]
+                       / tok.size)(params)
+    w_ce = grad_ce["blocks"]["layer0"]["mixer"]["w"]
+    assert float(jnp.max(jnp.abs(grads["blocks"]["layer0"]["mixer"]["w"] - w_ce))) > 0
+
+
+def test_a_pattern_without_terms_adds_none():
+    model = tiny.spec("mamba", f32=True)["config_doc"]["model"]
+    params = R.make_params(model, 5)
+    b = feed.worker_batches(tiny.spec("mamba")["traffic"], model["vocab"], 5, 0)[0]
+    ce, aux = R.loss_terms(params, jnp.asarray(b["tokens"]), jnp.asarray(b["labels"]), model)
+    assert aux is None and float(ce) > 0
+
+
+def test_a_tiny_model_of_its_own():
+    """``tiny.spec`` takes an ``(arch, model)`` pair; the program's layout
+    of that model is checked against the reference's, found by the lookup."""
+    own = dict(tiny.MAMBA, n_layers=3)
+    spec = tiny.spec(("mamba2-130m", own), workers=2)
+    assert spec["cell"] == {"name": "test.mamba2-130m", "chips": 2}
+    assert spec["config_doc"] == {"arch": "mamba2-130m", "model": own}
+    assert spec["config_doc"]["model"] is not own
+    cfg = program.model_config(spec["config_doc"], spec["traffic"])
+    program.check_layout(cfg, R.param_layout(own))
+    assert R.param_layout(own)["blocks"]["layer0"]["mixer"]["A_log"].shape == (3, 8)

@@ -43,6 +43,20 @@ def test_workload_resolves_to_its_files(workload):
         assert os.path.exists(cells.metric_path(m["name"]))
 
 
+PER_LAYER = {
+    "mamba2-130m.diana.b8s4096": ["step_mfu", "diana_kernel_ms", "diana_kernel_roofline",
+                                  "ssd_kernel_ms", "device_idle_share"],
+    "mamba2-130m.diana.w4.b8s4096": ["step_mfu", "diana_kernel_ms", "diana_kernel_roofline",
+                                     "ssd_kernel_ms", "allgather_ms", "device_idle_share"],
+}
+
+
+@pytest.mark.parametrize("workload", sorted(PER_LAYER))
+def test_per_layer_metrics_of_each_cell(workload):
+    names = [m["name"] for m in cells.resolve(workload)["per_layer"]]
+    assert [n for n in names if n in PER_LAYER[workload]] == PER_LAYER[workload]
+
+
 def test_config_files_state_their_cuts():
     for c in SPEC["configs"]:
         doc = cells.load_json(os.path.join(ROOT, c["file"]))

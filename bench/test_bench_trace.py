@@ -1,10 +1,13 @@
 """The trace reduction, on a trace written by hand in the TPU's layout and
-on one recorded on a v5e (granite l4 on one chip, cut to one step)."""
+on two recorded on a v5e, each cut to one step: granite l4 and mamba2-130m,
+one chip each."""
 
 import os
+from collections import defaultdict
 
 import pytest
 
+from bench import cells, flops
 from bench import trace as TR
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "testdata")
@@ -42,8 +45,8 @@ def test_hand_trace():
     # chip 0: [10, 60] u [70, 80] u [95, 100 (clipped)]; chip 1: all 100
     assert r["busy_s"] == pytest.approx((65 + 100) / 2 * us)
     assert 1 - r["busy_s"] / r["window_s"] == pytest.approx(0.175)
-    # kernels 10 + 10 us on chip 0, none on chip 1
-    assert r["kernel_s_per_chip"] == pytest.approx(10 * us)
+    # DIANA's kernels 10 + 10 us on chip 0, none on chip 1
+    assert r["kernel_s_per_chip"] == pytest.approx({"diana": 10 * us, "ssd": 0, "other": 0})
     # all-gather start 2 + done 5 us on the ops line (the async line is not busy time)
     assert r["gather_s_per_chip"] == pytest.approx(3.5 * us)
     gaps = r["breakdown"]["idle_gaps"]
@@ -82,5 +85,49 @@ def test_recorded_trace():
     kernels = [(f, min(b, hi) - max(a, lo)) for ops in devices.values()
                for _, f, a, b in ops if "tpu_custom_call" in f and b > lo and a < hi]
     assert len(kernels) == 2                    # encode and decode_sum_apply
-    assert r["kernel_s_per_chip"] == pytest.approx(sum(t for _, t in kernels))
+    assert r["kernel_s_per_chip"] == pytest.approx(
+        {"diana": sum(t for _, t in kernels), "ssd": 0, "other": 0})
     assert r["gather_s_per_chip"] == 0          # one chip: no exchange
+
+
+@pytest.mark.parametrize("name,family", [
+    ("quantize_pack_prng.1", "diana"), ("unpack_reduce_apply.1", "diana"),
+    ("nat_decode_sum_apply.3", "diana"), ("sparse_gather.2", "diana"), ("dense_copy.1", "diana"),
+    ("ssd_chunk_scan.15", "ssd"), ("ssd_chunk_scan_bwd.9", "ssd"),
+    ("other_kernel.1", "other"), ("fusion.207", "other")])
+def test_kernel_family_by_name(name, family):
+    assert TR.kernel_family(name) == family
+
+
+def test_kernel_sums_by_name():
+    """mamba2-130m's step: DIANA's encode and decode kernels, the SSD's
+    forward (run twice: forward and recompute) and backward kernels, and a
+    kernel of neither (added by hand), each in its own sum; the metrics read
+    DIANA's and the SSD's sums apart."""
+    pd = TR.load(os.path.join(DATA, "trace_m1.pbtxt"))
+    r = TR.reduce(pd, {"kernel": set(), "gather": set()})
+    assert r["chips_traced"] == 1 and r["steps"] == 1
+    _, devices = TR.events(pd)
+    by_kernel = defaultdict(float)
+    for ops in devices.values():
+        for name, full, a, b in ops:
+            if "tpu_custom_call" in full:
+                by_kernel[name.rsplit(".", 1)[0]] += b - a
+    assert set(by_kernel) == {"quantize_pack_prng", "unpack_reduce_apply", "ssd_chunk_scan",
+                              "ssd_chunk_scan_bwd", "other_kernel"}
+    k = r["kernel_s_per_chip"]
+    assert k["diana"] == pytest.approx(by_kernel["quantize_pack_prng"]
+                                       + by_kernel["unpack_reduce_apply"])
+    assert k["ssd"] == pytest.approx(by_kernel["ssd_chunk_scan"] + by_kernel["ssd_chunk_scan_bwd"])
+    assert k["other"] == pytest.approx(50e-6)
+
+    model = cells.load_json(cells.config_path("mamba2-130m"))["model"]
+    ctx = {"trace": r, "peaks": flops.peaks_for("TPU v5 lite"), "model": model,
+           "param_count": flops.param_count(model), "workers": 1}
+    read = {n: cells.load_metric_reader(n)(ctx)
+            for n in ("diana_kernel_ms", "ssd_kernel_ms", "diana_kernel_roofline")}
+    assert read["diana_kernel_ms"] == pytest.approx(1e3 * k["diana"])
+    assert read["ssd_kernel_ms"] == pytest.approx(1e3 * k["ssd"])
+    # as recorded: the SSD's kernels about 99.95 ms a step, DIANA's about 9.5
+    assert 99 < read["ssd_kernel_ms"] < 101 and 9 < read["diana_kernel_ms"] < 10
+    assert 25 < read["diana_kernel_roofline"] < 31

@@ -17,15 +17,18 @@ ARCH = {"mamba": ("mamba2-130m", MAMBA)}
 E2E = [{"name": "tokens_per_s", "unit": "tokens/s"}, {"name": "setup_s", "unit": "s"}]
 
 
-def spec(family: str, workers: int = 1, batch: int = 2, limits=None, f32: bool = False):
-    arch, model = ARCH[family]
+def spec(family, workers: int = 1, batch: int = 2, limits=None, f32: bool = False):
+    """A cell at test size.  ``family`` names an entry of ``ARCH``, or is an
+    ``(arch, model)`` pair: the registry's name and a model of its own."""
+    arch, model = ARCH[family] if isinstance(family, str) else family
+    name = family if isinstance(family, str) else arch
     model = copy.deepcopy(model)
     if f32:
         model.update(param_dtype="float32", compute_dtype="float32")
     traffic = {"seq_len": 64, "batch_per_worker": batch, "workers": workers,
                "compression": "diana", "inner_optimizer": "momentum", "lr": 3e-4,
                "beta": 0.9, "pool": 4, "noise": 0.1, "grammars": 1}
-    return {"cell": {"name": f"test.{family}", "chips": workers},
+    return {"cell": {"name": f"test.{name}", "chips": workers},
             "config_doc": {"arch": arch, "model": model}, "traffic": traffic,
             "limits": limits, "end_to_end": E2E, "per_layer": []}
 

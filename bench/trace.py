@@ -6,10 +6,13 @@ instruction (a loop's event holds its body's events); the benchmark's host
 spans (``bench.window``, ``bench.step``, ``bench.feed``, ``bench.block``)
 are events on the host plane, on the same clock.  An operation is a kernel
 if it is a ``tpu_custom_call``, and an all-gather if it is one or calls one
-(by the compiled program's text).
+(by the compiled program's text).  A kernel's instruction is named after the
+program's wrapper that called ``pallas_call``, and that name says whose it
+is (``KERNEL_FAMILIES``).
 
 * busy: the union of the device's operation intervals inside the window;
-* kernel and all-gather time: the sum of their events' durations;
+* kernel time, by family, and all-gather time: the sum of their events'
+  durations;
 * idle gaps: the stretches of the window with no operation on the device,
   each labelled by the innermost host span that covers its middle.
 """
@@ -23,6 +26,12 @@ from collections import defaultdict
 from typing import Dict, Iterable, List, Sequence, Tuple
 
 OPS_LINE = "XLA Ops"
+# the program's kernels by the prefix of their instruction names: DIANA's
+# encode and decode wrappers (``kernels/quantize_pack.py``, ``unpack_reduce.py``,
+# ``nat_pack.py``, ``sparse.py``, ``dense.py``) and Mamba-2's SSD chunk scan
+# (``kernels/ssd.py``); any other kernel is "other"
+KERNEL_FAMILIES = (("diana", ("quantize_pack", "unpack_reduce", "nat_", "sparse_", "dense_")),
+                   ("ssd", ("ssd_chunk_scan",)))
 WINDOW_SPAN = "bench.window"
 SPAN_PREFIX = "bench."
 TOP = 10
@@ -197,20 +206,31 @@ def is_kernel(name: str, full: str, classes) -> bool:
     return 'custom_call_target="tpu_custom_call"' in full or name in classes["kernel"]
 
 
+def kernel_family(name: str) -> str:
+    """Whose kernel the instruction ``name`` is: a family of
+    ``KERNEL_FAMILIES``, or ``"other"``."""
+    for family, prefixes in KERNEL_FAMILIES:
+        if name.startswith(prefixes):
+            return family
+    return "other"
+
+
 def is_gather(name: str, full: str, classes) -> bool:
     return name in classes["gather"] or bool(_GATHER_RE.search(" " + full.split("=", 1)[-1]))
 
 
 def reduce(pd, classes: Dict[str, set]) -> Dict:
-    """Busy, kernel and all-gather seconds per chip over the traced window,
-    and the breakdown of device operations (by self time) and idle gaps."""
+    """Busy, kernel (by family) and all-gather seconds per chip over the
+    traced window, and the breakdown of device operations (by self time) and
+    idle gaps."""
     spans, devices = events(pd)
     win = [(a, b) for n, a, b in spans if n == WINDOW_SPAN]
     if not win or not devices:
         raise ValueError("the trace holds no bench.window span or no device operations")
     lo, hi = win[0]
     steps = sum(1 for n, a, b in spans if n == "bench.step" and a >= lo and b <= hi)
-    busy_s, kernel_s, gather_s = [], [], []
+    busy_s, gather_s = [], []
+    kernel_s = {f: 0.0 for f, _ in KERNEL_FAMILIES + (("other", ()),)}
     by_op: Dict[str, float] = defaultdict(float)
     all_gaps = []
     n_dev = len(devices)
@@ -218,7 +238,9 @@ def reduce(pd, classes: Dict[str, set]) -> Dict:
         inside = [(n, f, max(a, lo), min(b, hi)) for n, f, a, b in ops if b > lo and a < hi]
         busy = union(((a, b) for _, _, a, b in inside), lo, hi)
         busy_s.append(sum(b - a for a, b in busy))
-        kernel_s.append(sum(b - a for n, f, a, b in inside if is_kernel(n, f, classes)))
+        for n, f, a, b in inside:
+            if is_kernel(n, f, classes):
+                kernel_s[kernel_family(n)] += (b - a) / n_dev
         gather_s.append(sum(b - a for n, f, a, b in inside if is_gather(n, f, classes)))
         for k, v in self_times(inside).items():
             by_op[k] += v / n_dev
@@ -229,7 +251,7 @@ def reduce(pd, classes: Dict[str, set]) -> Dict:
         "steps": steps,
         "chips_traced": n_dev,
         "busy_s": sum(busy_s) / n_dev,
-        "kernel_s_per_chip": sum(kernel_s) / n_dev,
+        "kernel_s_per_chip": kernel_s,
         "gather_s_per_chip": sum(gather_s) / n_dev,
         "breakdown": {
             "device_ops": [[k, v] for k, v in sorted(by_op.items(), key=lambda kv: -kv[1])[:TOP]],
