@@ -5,74 +5,97 @@ to the program can change them.  They count what the algorithm requires,
 not what an implementation happens to do: recomputation (remat), vocabulary
 padding, capacity slots left empty and the upper triangle of a causal
 product are not counted.
+
+A layer kind's counts come from its own file, ``bench/layers/<kind>.py``,
+where it defines ``matmul_params(model)`` (the weights each token multiplies
+once in one layer's forward pass, at this chip's share) and
+``sequence_flops(model, seq_len)`` (one layer's forward operations per token
+beyond those products); ``attn``, ``moe`` and ``dense`` keep the built-in
+counts below where their file defines none.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Dict
+import os
+from typing import Dict, Optional
 
 
-def _mixer_params(model, spec) -> int:
+def _attn_params(model) -> int:
     d = model["d_model"]
-    if spec["mixer"] == "mamba":
-        s = model["ssm"]
-        d_in = s["expand"] * d
-        h = d_in // s["head_dim"]
-        gn = s["n_groups"] * s["d_state"]
-        conv = s["conv_width"] * (d_in + 2 * gn)          # depthwise MACs
-        return d * (2 * d_in + 2 * gn + h) + conv + d_in * d
     h, hkv, dh = model["n_heads"], model["n_kv_heads"], model["head_dim"]
     return d * (h + 2 * hkv) * dh + h * dh * d
 
 
-def _mlp_params_active(model, spec) -> int:
-    d = model["d_model"]
-    if spec["mlp"] == "moe":
-        m = model["moe"]
-        return d * m["n_experts"] + m["top_k"] * 3 * d * m["d_ff"]
-    if spec["mlp"] == "dense":
-        return 3 * d * model["d_ff"]
-    return 0
+def _attn_sequence_flops(model, seq_len: int) -> int:
+    """Causal attention: a query at position t reads t + 1 keys (QK^T, PV)."""
+    return 2 * model["n_heads"] * model["head_dim"] * (seq_len + 1)
 
 
-def matmul_params_per_token(model) -> int:
+def _moe_params(model) -> int:
+    """The router and the top-k SwiGLU experts a token is routed to."""
+    d, m = model["d_model"], model["moe"]
+    return d * m["n_experts"] + m["top_k"] * 3 * d * m["d_ff"]
+
+
+def _dense_params(model) -> int:
+    return 3 * model["d_model"] * model["d_ff"]
+
+
+# the counts of the kinds that have no file of their own, or whose file
+# defines none: (matmul_params, sequence_flops)
+BUILT_IN = {"attn": (_attn_params, _attn_sequence_flops),
+            "moe": (_moe_params, lambda model, seq_len: 0),
+            "dense": (_dense_params, lambda model, seq_len: 0)}
+
+
+def kind_counts(kind: str, layers: Optional[str] = None):
+    """(matmul_params(model), sequence_flops(model, seq_len)) of one layer
+    of a kind: those its file ``<layers>/<kind>.py`` defines, else the
+    built-in ones.  A kind with neither is an error that names the file."""
+    from . import cells
+
+    layers = layers or os.path.join(cells.BENCH, "layers")
+    path = os.path.join(layers, kind + ".py")
+    mod = cells.load_module(path) if os.path.exists(path) else None
+    built_in = BUILT_IN.get(kind, (None, None))
+    counts = tuple(getattr(mod, name, None) or fn for name, fn in
+                   zip(("matmul_params", "sequence_flops"), built_in))
+    if None in counts:
+        raise ValueError(f"no count for layer kind {kind!r}: define matmul_params and "
+                         f"sequence_flops in {os.path.relpath(path, cells.ROOT)}")
+    return counts
+
+
+def _kinds(model):
+    return [k for spec in model["pattern"] for k in (spec["mixer"], spec["mlp"]) if k != "none"]
+
+
+def matmul_params_per_token(model, layers: Optional[str] = None) -> int:
     """Weights each token multiplies once in the forward pass: every layer's
     projections (experts: only the top-k it is routed to, plus the router)
     and the head over the published vocabulary.  The embedding lookup is a
     gather and is not counted."""
-    period = model["pattern"]
-    reps = model["n_layers"] // len(period)
-    per_block = sum(_mixer_params(model, s) + _mlp_params_active(model, s) for s in period)
+    reps = model["n_layers"] // len(model["pattern"])
+    per_block = sum(kind_counts(k, layers)[0](model) for k in _kinds(model))
     return reps * per_block + model["vocab"] * model["d_model"]
 
 
-def sequence_flops_per_token(model, seq_len: int) -> float:
-    """Forward operations per token of the sequence mixers beyond their
-    projections: causal attention (a query at position t reads t + 1 keys)
-    and Mamba-2's chunked SSD (causal within a chunk of ``chunk_size``)."""
-    period = model["pattern"]
-    reps = model["n_layers"] // len(period)
+def sequence_flops_per_token(model, seq_len: int, layers: Optional[str] = None) -> float:
+    """Forward operations per token of the layers beyond their projections,
+    such as causal attention's products or Mamba-2's chunked SSD."""
+    reps = model["n_layers"] // len(model["pattern"])
     total = 0.0
-    for spec in period:
-        if spec["mixer"] == "mamba":
-            s = model["ssm"]
-            d_in = s["expand"] * model["d_model"]
-            h, p, n, g = d_in // s["head_dim"], s["head_dim"], s["d_state"], s["n_groups"]
-            q = min(s["chunk_size"], seq_len)
-            # C B^T per group and (C B^T o L) X per head, causal in the chunk;
-            # each chunk's state B^T X and its readout C h, per head.
-            total += g * n * (q + 1) + h * p * (q + 1) + 4 * h * n * p
-        else:
-            h, dh = model["n_heads"], model["head_dim"]
-            total += 2 * h * dh * (seq_len + 1)           # QK^T and PV
+    for k in _kinds(model):
+        total += kind_counts(k, layers)[1](model, seq_len)
     return reps * total
 
 
-def train_flops_per_token(model, seq_len: int) -> float:
+def train_flops_per_token(model, seq_len: int, layers: Optional[str] = None) -> float:
     """Forward and backward operations required per token: three times the
     forward pass (the backward pass takes two products per forward one)."""
-    fwd = 2.0 * matmul_params_per_token(model) + sequence_flops_per_token(model, seq_len)
+    fwd = (2.0 * matmul_params_per_token(model, layers)
+           + sequence_flops_per_token(model, seq_len, layers))
     return 3.0 * fwd
 
 
@@ -80,7 +103,6 @@ def peaks_for(device_kind: str) -> Dict[str, float]:
     """The chip's peaks from ``bench/peaks.json``; a kind not in the table is
     an error, never a default."""
     import json
-    import os
 
     with open(os.path.join(os.path.dirname(os.path.abspath(__file__)), "peaks.json")) as f:
         table = json.load(f)

@@ -11,7 +11,10 @@ the first and the parameters after the third are what the comparison reads.
 Window: the compiled step on the next batch of the pool, each step ended in
 ``block_until_ready``, for ``seconds`` seconds, under host spans
 (``bench.step``, ``bench.feed``, ``bench.block``) that a traced run
-attributes idle gaps to.
+attributes idle gaps to.  A traced run reduces its trace twice, once by
+operation (``bench/trace.py``, the context's ``trace``) and once by the
+program's named scopes (``bench/scopes.py``, its ``scopes``), for the
+per-layer metrics' readers.
 
 Afterwards: the peak device memory is read, the program's state is freed,
 and the reference runs the same three steps from the same weights (made
@@ -263,17 +266,22 @@ def run(spec: Dict, seed: int, seconds: float, trace: bool, *, t_start: float,
         memory_peak_bytes=peak, model=model, traffic=traffic,
         peaks=_peaks(devices[0]), param_count=flops.param_count(model),
         train_flops_per_token=flops.train_flops_per_token(model, traffic["seq_len"]),
-        trace=None,
+        trace=None, scopes=None,
     )
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
               "count": chips, "memory_peak_bytes": peak}
     result = {"correct": bool(correct), "attempted": len(step_times), "failed": failed}
     wanted = spec["per_layer"] if trace else spec["end_to_end"]
     if trace:
+        from . import scopes as SC
         from . import trace as TR
 
-        ctx["trace"] = TR.reduce(TR.load(TR.find_xplane(tdir)), TR.classify_hlo(hlo))
+        t_red = time.perf_counter()
+        pd = TR.load(TR.find_xplane(tdir))
+        ctx["trace"] = TR.reduce(pd, TR.classify_hlo(hlo))
+        ctx["scopes"] = SC.reduce_scopes(pd, hlo)
         shutil.rmtree(tdir, ignore_errors=True)
+        log(f"trace reduced in {time.perf_counter() - t_red:.3f}s")
         device.update(busy_s=ctx["trace"]["busy_s"], window_s=ctx["trace"]["window_s"])
     metrics = {}
     for m_ in wanted:

@@ -1,6 +1,17 @@
 """Mamba-2's mixer (arXiv:2405.21060), as a layer kind of the reference:
 in-projection, causal depthwise convolution, the SSD scan by the paper's
-minimal chunked listing, gated RMSNorm, out-projection."""
+minimal chunked listing, gated RMSNorm, out-projection.
+
+The gated RMSNorm normalises ``y * silu(z)`` over each of the ``n_groups``
+groups of ``d_in / n_groups`` channels apart, each group with its own mean
+square, and scales by one vector of ``d_in``: mamba_ssm's ``Mamba2``
+(``RMSNormGated(d_ssm, norm_before_gate=False, group_size=d_ssm //
+ngroups)``), as transformers' Zamba2 copies it (``Zamba2RMSNormGated``).
+With one group it is the RMSNorm over the whole inner width.
+
+The yardstick's counts of one layer (``bench/flops.py``) are here too:
+:func:`matmul_params` and :func:`sequence_flops`.
+"""
 
 from __future__ import annotations
 
@@ -9,17 +20,40 @@ import math
 import jax
 import jax.numpy as jnp
 
-from bench.reference import F32, HIGHEST, Leaf, rms_norm
+from bench.reference import F32, HIGHEST, rms_norm
 
 SSD_CHUNK = 128           # the reference's own SSD chunk (any size is exact)
 
 
 def _dims(model):
+    """The layer's sizes.  The inner width is ``expand * d_model`` (Mamba-2),
+    or ``num_heads * head_dim`` where the configuration gives the heads
+    (Nemotron-H's ``mamba_num_heads``: 64 heads of 64 beside a hidden size
+    of 2,688, no whole multiple of it)."""
     d = model["d_model"]
     ssm = model["ssm"]
-    d_in = ssm["expand"] * d
+    d_in = ssm["num_heads"] * ssm["head_dim"] if "num_heads" in ssm else ssm["expand"] * d
     return dict(d=d, d_in=d_in, h=d_in // ssm["head_dim"], p=ssm["head_dim"],
                 n=ssm["d_state"], g=ssm["n_groups"], w=ssm["conv_width"])
+
+
+def matmul_params(model) -> int:
+    """Weights each token multiplies once in a layer's forward pass: the
+    in- and out-projections and the depthwise convolution's MACs."""
+    m = _dims(model)
+    d, d_in, gn = m["d"], m["d_in"], m["g"] * m["n"]
+    conv = m["w"] * (d_in + 2 * gn)
+    return d * (2 * d_in + 2 * gn + m["h"]) + conv + d_in * d
+
+
+def sequence_flops(model, seq_len: int) -> int:
+    """Forward operations per token of a layer beyond its projections:
+    C B^T per group and (C B^T o L) X per head, causal within a chunk of
+    ``chunk_size``; each chunk's state B^T X and its readout C h, per head."""
+    m = _dims(model)
+    h, p, n, g = m["h"], m["p"], m["n"], m["g"]
+    q = min(model["ssm"]["chunk_size"], seq_len)
+    return g * n * (q + 1) + h * p * (q + 1) + 4 * h * n * p
 
 
 def _a_log(key, shape):
@@ -105,6 +139,20 @@ def ssd(x, a, b, c, chunk, dot):
     return (y_diag + y_off).reshape(bsz, l, h, p)
 
 
+def gated_rms_norm(y, z, scale, groups, eps):
+    """RMSNorm of ``y * silu(z)`` over each of ``groups`` equal groups of the
+    last axis apart, then times ``scale`` over the whole axis.  One group is
+    ``rms_norm`` itself, without the reshape, whose compiled gradient rounds
+    differently."""
+    x = y * jax.nn.silu(z)
+    if groups == 1:
+        return rms_norm(x, scale, eps)
+    *lead, width = x.shape
+    x = x.reshape(*lead, groups, width // groups)
+    x = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
+    return x.reshape(*lead, width) * scale
+
+
 def apply(p, x, model, dot):
     m = _dims(model)
     d_in, h, hp, n, g, w = m["d_in"], m["h"], m["p"], m["n"], m["g"], m["w"]
@@ -123,5 +171,5 @@ def apply(p, x, model, dot):
     y = ssd(xs * dt[..., None], a * dt, braw.reshape(bsz, s, g, n),
             craw.reshape(bsz, s, g, n), min(SSD_CHUNK, s), dot)
     y = (y + p["D"][:, None] * xs).reshape(bsz, s, d_in)
-    y = rms_norm(y * jax.nn.silu(z), p["norm_scale"], model["norm_eps"])
+    y = gated_rms_norm(y, z, p["norm_scale"], g, model["norm_eps"])
     return dot("bse,ed->bsd", y, p["out_proj"]), 0

@@ -405,7 +405,8 @@ def _step_len(steps) -> float:
 
 def layer_metrics(red: Dict) -> Dict[str, Optional[float]]:
     """The per-layer numbers the scopes give, per chip and step; None where
-    the trace holds no program scope (a program without them)."""
+    the trace holds no program scope (a program without them), and for a
+    sum whose scopes the trace does not hold (no DIANA round, say)."""
     by = red["scope_ms_per_step"]
     if not any(s != UNSCOPED for s in by):
         return {k: None for k in ("forward_ms", "backward_ms", "optimizer_ms",
@@ -413,8 +414,8 @@ def layer_metrics(red: Dict) -> Dict[str, Optional[float]]:
                                   "allgather_exposed_ms", "allgather_mb")}
 
     def total(pred, phases=PHASES):
-        return sum(v for s, p in by.items() if pred(s) for ph, v in p.items()
-                   if ph in phases)
+        times = [v for s, p in by.items() if pred(s) for ph, v in p.items() if ph in phases]
+        return sum(times) if times else None
 
     gathered = red["allgather_bytes_per_step"]
     return {

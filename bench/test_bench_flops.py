@@ -6,8 +6,10 @@ import os
 import pytest
 
 from bench import flops
+from bench import reference as R
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
+TEST_LAYERS = os.path.join(BENCH, "testdata", "layers")
 
 
 # granite-3.0-3b-a800m's published widths, cut to 4 of its 32 layers
@@ -50,6 +52,38 @@ def test_sequence_terms():
     per_layer = 1 * 128 * (q + 1) + 24 * 64 * (q + 1) + 4 * 24 * 128 * 64
     assert flops.sequence_flops_per_token(m, 4096) == 24 * per_layer
     assert flops.train_flops_per_token(m, 4096) == 3 * (2 * 128_882_688 + 24 * per_layer)
+
+
+def test_mamba2_counts_to_the_bit():
+    """The counts live in ``bench/layers/mamba.py`` and give what the hand
+    count above gives, to the bit."""
+    m = _model("mamba2-130m")
+    mamba = R.layer_kind("mamba")
+    assert flops.kind_counts("mamba") == (mamba.matmul_params, mamba.sequence_flops)
+    assert mamba.matmul_params(m) == 3_761_152
+    assert flops.train_flops_per_token(m, 4096) == 860_709_888.0
+    assert flops.sequence_flops_per_token(m, 4096) == 24 * 1_214_080
+
+
+def test_a_kind_counted_by_its_own_file():
+    """``bench/testdata/layers/toy.py`` defines both counts and is counted
+    by them; ``dense.py`` there defines neither and keeps the built-in
+    count; a kind with neither a count of its own nor a built-in one is an
+    error that names its file."""
+    d, f, v, s = 64, 96, 500, 128
+    model = {"n_layers": 4, "d_model": d, "d_ff": f, "vocab": v, "param_dtype": "float32",
+             "tie_embeddings": True,
+             "pattern": [{"mixer": "toy", "mlp": "dense"}, {"mixer": "toy", "mlp": "none"}]}
+    assert R.param_layout(model, TEST_LAYERS)["blocks"]["layer0"]["mlp"]["up"].shape == (2, d, f)
+    assert flops.matmul_params_per_token(model, TEST_LAYERS) == \
+        2 * (d * d + 3 * d * f + d * d) + v * d
+    assert flops.sequence_flops_per_token(model, s, TEST_LAYERS) == 2 * (d + d)
+    assert flops.train_flops_per_token(model, s, TEST_LAYERS) == \
+        3 * (2 * (2 * (2 * d * d + 3 * d * f) + v * d) + 4 * d)
+    with pytest.raises(ValueError, match="no count for layer kind 'toy': define "
+                                         "matmul_params and sequence_flops in "
+                                         "bench/layers/toy.py"):
+        flops.matmul_params_per_token(model)
 
 
 def test_kernel_bytes_by_shape():

@@ -58,6 +58,55 @@ def test_ssd_equals_the_recurrence():
     np.testing.assert_allclose(got, jnp.moveaxis(want, 0, 1), rtol=1e-4, atol=1e-4)
 
 
+def _gate_inputs(width):
+    rng = np.random.default_rng(4)
+    y, z = (jnp.asarray(rng.standard_normal((2, 5, width)), jnp.float32) for _ in range(2))
+    scale = jnp.asarray(rng.uniform(0.5, 1.5, width), jnp.float32)
+    return y, z, scale
+
+
+def test_gated_norm_per_group():
+    """Two groups: each normalised by its own mean square (a direct formula
+    per group), then scaled over the whole width; not the whole-width norm."""
+    y, z, scale = _gate_inputs(24)
+    got = R.layer_kind("mamba").gated_rms_norm(y, z, scale, 2, 1e-5)
+    x = np.asarray(y * jax.nn.silu(z), np.float64)
+    want = np.concatenate([g / np.sqrt(np.mean(g * g, -1, keepdims=True) + 1e-5)
+                           for g in (x[..., :12], x[..., 12:])], -1) * np.asarray(scale)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    whole = R.rms_norm(y * jax.nn.silu(z), scale, 1e-5)
+    assert float(jnp.max(jnp.abs(got - whole))) > 1e-3
+
+
+def test_gated_norm_one_group_is_the_whole_width_norm():
+    """One group is the RMSNorm over the whole width, bit for bit, eager and
+    jitted."""
+    y, z, scale = _gate_inputs(48)
+    norm = R.layer_kind("mamba").gated_rms_norm
+    whole = R.rms_norm(y * jax.nn.silu(z), scale, 1e-5)
+    assert bool(jnp.all(norm(y, z, scale, 1, 1e-5) == whole))
+    jitted = jax.jit(lambda *a: norm(*a, 1, 1e-5))(y, z, scale)
+    assert bool(jnp.all(jitted == jax.jit(
+        lambda y_, z_, s_: R.rms_norm(y_ * jax.nn.silu(z_), s_, 1e-5))(y, z, scale)))
+
+
+def test_mamba_inner_width_from_its_heads():
+    """Where the configuration gives ``num_heads``, the inner width is the
+    heads' (not ``expand * d_model``): in the layout, the layer and the
+    yardstick's count, here with three groups."""
+    ssm = dict(tiny.MAMBA["ssm"], num_heads=3, n_groups=3, d_state=8)
+    model = dict(tiny.MAMBA, d_model=40, ssm=ssm, param_dtype="float32")
+    mamba = R.layer_kind("mamba")
+    mixer = R.param_layout(model)["blocks"]["layer0"]["mixer"]
+    assert mixer["out_proj"].shape == (2, 48, 40) and mixer["A_log"].shape == (2, 3)
+    assert mixer["in_proj"].shape == (2, 40, 2 * 48 + 2 * 3 * 8 + 3)
+    assert mamba.matmul_params(model) == 40 * (2 * 48 + 48 + 3) + 4 * (48 + 48) + 48 * 40
+    p = jax.tree_util.tree_map(lambda a: a[0], R.make_params(model, 9)["blocks"]["layer0"])
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 64, 40))
+    y, _ = mamba.apply(p["mixer"], x, model, R.make_dot("f32"))
+    assert y.shape == x.shape and bool(jnp.all(jnp.isfinite(y)))
+
+
 def test_quantize_is_ternary_per_block_and_unbiased():
     x = jax.random.normal(jax.random.PRNGKey(1), (3, 700))
     q = R.quantize(x, jax.random.PRNGKey(2), 256)

@@ -6,6 +6,7 @@ import os
 
 import pytest
 
+from bench import cells
 from bench import scopes as SC
 from bench import trace as TR
 
@@ -280,6 +281,35 @@ def test_layer_metrics():
     assert lm["diana_decode_ms"] == pytest.approx(0.5 * ms)
     assert lm["allgather_exposed_ms"] == pytest.approx(2 * ms)
     assert lm["allgather_mb"] == pytest.approx(1536 / 1e6)
+
+
+def test_layer_metrics_read_nothing_of_scopes_the_step_lacks():
+    """A step with no DIANA round and no gather (operator ``none`` on one
+    chip): its sums over absent scopes read None, not 0."""
+    red = {"scope_ms_per_step": {"model.mixer": {"forward": 3.0, "backward": 5.0},
+                                 "train.optimizer": {"forward": 0.5}},
+           "allgather_bytes_per_step": 0.0, "allgather_exposed_ms": 0.0}
+    assert SC.layer_metrics(red) == {
+        "forward_ms": 3.0, "backward_ms": 5.0, "optimizer_ms": 0.5, "diana_round_ms": None,
+        "diana_decode_ms": None, "allgather_exposed_ms": None, "allgather_mb": None}
+
+
+SCOPE_METRICS = ["forward_ms","backward_ms", "optimizer_ms", "diana_round_ms",
+                 "diana_decode_ms", "allgather_exposed_ms", "allgather_mb"]
+
+
+@pytest.mark.parametrize("name", SCOPE_METRICS)
+def test_scope_metric_reader(name):
+    """Each reader ``bench/metrics/<name>.py`` reads the traced run's
+    reduction by scope from the context, and nothing in an untraced run."""
+    red = SC.reduce_scopes(_trace(), HLO)
+    read = cells.load_metric_reader(name)
+    assert read({"trace": None, "scopes": red}) == SC.layer_metrics(red)[name]
+    assert read({"trace": None, "scopes": red}) > 0
+    assert read({"trace": None, "scopes": None}) is None
+    # a program without scopes: nothing to read
+    pd = TR.load(os.path.join(DATA, "trace_g1.pbtxt"))
+    assert read({"trace": None, "scopes": SC.reduce_scopes(pd, "")}) is None
 
 
 @pytest.mark.parametrize("name", ["trace_hand.pbtxt", "trace_g1.pbtxt"])

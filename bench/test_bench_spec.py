@@ -43,11 +43,13 @@ def test_workload_resolves_to_its_files(workload):
         assert os.path.exists(cells.metric_path(m["name"]))
 
 
+SCOPED = ["forward_ms", "backward_ms", "optimizer_ms", "diana_round_ms", "diana_decode_ms"]
 PER_LAYER = {
     "mamba2-130m.diana.b8s4096": ["step_mfu", "diana_kernel_ms", "diana_kernel_roofline",
-                                  "ssd_kernel_ms", "device_idle_share"],
+                                  "ssd_kernel_ms", *SCOPED, "device_idle_share"],
     "mamba2-130m.diana.w4.b8s4096": ["step_mfu", "diana_kernel_ms", "diana_kernel_roofline",
-                                     "ssd_kernel_ms", "allgather_ms", "device_idle_share"],
+                                     "ssd_kernel_ms", *SCOPED, "allgather_exposed_ms",
+                                     "allgather_mb", "device_idle_share"],
 }
 
 

@@ -1,6 +1,7 @@
 """A layer kind for the tests of the lookup: one square projection, with
 a term for the loss that depends on the batch as a whole (the square of the
-output's mean), and an initialiser of its own."""
+output's mean), an initialiser of its own, and its own counts for the
+yardstick (``bench/flops.py``)."""
 
 import jax
 import jax.numpy as jnp
@@ -26,3 +27,12 @@ def layout(model, stacked) -> dict:
 def apply(p, x, model, dot):
     y = dot("bsd,de->bse", x, p["w"])
     return y, AUX_WEIGHT * jnp.square(jnp.mean(y))
+
+
+def matmul_params(model) -> int:
+    return model["d_model"] ** 2
+
+
+def sequence_flops(model, seq_len: int) -> int:
+    """The output's mean: one addition per channel and token."""
+    return model["d_model"]

@@ -11,8 +11,7 @@ program's wrapper that called ``pallas_call``, and that name says whose it
 is (``KERNEL_FAMILIES``).
 
 * busy: the union of the device's operation intervals inside the window;
-* kernel time, by family, and all-gather time: the sum of their events'
-  durations;
+* kernel time, by family: the sum of its events' durations;
 * idle gaps: the stretches of the window with no operation on the device,
   each labelled by the innermost host span that covers its middle.
 """
@@ -220,16 +219,15 @@ def is_gather(name: str, full: str, classes) -> bool:
 
 
 def reduce(pd, classes: Dict[str, set]) -> Dict:
-    """Busy, kernel (by family) and all-gather seconds per chip over the
-    traced window, and the breakdown of device operations (by self time) and
-    idle gaps."""
+    """Busy and kernel (by family) seconds per chip over the traced window,
+    and the breakdown of device operations (by self time) and idle gaps."""
     spans, devices = events(pd)
     win = [(a, b) for n, a, b in spans if n == WINDOW_SPAN]
     if not win or not devices:
         raise ValueError("the trace holds no bench.window span or no device operations")
     lo, hi = win[0]
     steps = sum(1 for n, a, b in spans if n == "bench.step" and a >= lo and b <= hi)
-    busy_s, gather_s = [], []
+    busy_s = []
     kernel_s = {f: 0.0 for f, _ in KERNEL_FAMILIES + (("other", ()),)}
     by_op: Dict[str, float] = defaultdict(float)
     all_gaps = []
@@ -241,7 +239,6 @@ def reduce(pd, classes: Dict[str, set]) -> Dict:
         for n, f, a, b in inside:
             if is_kernel(n, f, classes):
                 kernel_s[kernel_family(n)] += (b - a) / n_dev
-        gather_s.append(sum(b - a for n, f, a, b in inside if is_gather(n, f, classes)))
         for k, v in self_times(inside).items():
             by_op[k] += v / n_dev
         all_gaps += gaps(busy, lo, hi)
@@ -252,7 +249,6 @@ def reduce(pd, classes: Dict[str, set]) -> Dict:
         "chips_traced": n_dev,
         "busy_s": sum(busy_s) / n_dev,
         "kernel_s_per_chip": kernel_s,
-        "gather_s_per_chip": sum(gather_s) / n_dev,
         "breakdown": {
             "device_ops": [[k, v] for k, v in sorted(by_op.items(), key=lambda kv: -kv[1])[:TOP]],
             "idle_gaps": [[_label(spans, (a + b) / 2), b - a] for a, b in all_gaps[:TOP]],
